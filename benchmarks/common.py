@@ -196,29 +196,30 @@ def time_call(fn, *args, iters: int = 3) -> float:
     return (time.time() - t0) / iters * 1e6
 
 
-def launch_count(fn, *args) -> int:
-    """Number of Pallas kernel launches one call of ``fn`` dispatches.
+def pallas_calls(fn, *args) -> list[dict]:
+    """The params of every ``pallas_call`` one call of ``fn`` dispatches
+    (``interpret``, ``grid_mapping``, ...), in jaxpr order.
 
-    Counts ``pallas_call`` equations in the jaxpr, recursing into nested
-    jaxprs (jit/scan/cond/... bodies). Backend-independent by design: it
-    works in interpret mode too, where ``.lower().compile()
-    .cost_analysis()`` carries no kernel-launch stats -- the jaxpr is the
-    dispatch plan either way, and on TPU one ``pallas_call`` equation is
-    one device kernel launch per grid.
+    Walks the jaxpr, recursing into nested jaxprs (jit/scan/cond/...
+    bodies). Backend-independent by design: it works in interpret mode
+    too, where ``.lower().compile().cost_analysis()`` carries no
+    kernel-launch stats -- the jaxpr is the dispatch plan either way.
     """
 
-    def count(jaxpr) -> int:
-        n = 0
+    def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                n += 1
+                yield eqn.params
             for v in eqn.params.values():
                 for sub in v if isinstance(v, (list, tuple)) else (v,):
-                    inner = getattr(sub, "jaxpr", None)
-                    if hasattr(sub, "eqns"):
-                        n += count(sub)
-                    elif inner is not None and hasattr(inner, "eqns"):
-                        n += count(inner)
-        return n
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner)
 
-    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def launch_count(fn, *args) -> int:
+    """Number of Pallas kernel launches one call of ``fn`` dispatches: on a
+    TPU one ``pallas_call`` equation is one device kernel launch per grid."""
+    return len(pallas_calls(fn, *args))

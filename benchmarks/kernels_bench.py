@@ -92,8 +92,9 @@ def _decode_step_rows(fast: bool) -> list[str]:
     :func:`benchmarks.common.launch_count`; the fused row asserts exactly
     one launch and bitwise logit/token parity with the unfused path before
     timing anything. Off-TPU the fused kernel runs in interpret mode --
-    the row is a parity/launch-count check only; on a TPU host the grid
-    lowers natively and the >= 1.3x tokens/s floor is asserted.
+    the row is a parity/launch-count check only. On a TPU host the floor
+    below (>= 1.3x tokens/s) would be asserted, but Mosaic does not lower
+    the grid yet (ROADMAP 1.3), so it has never been measured.
     """
     on_tpu = jax.devices()[0].platform == "tpu"
     cfg = ModelConfig(name="bench", family="dense", n_kv_heads=2).smoke()

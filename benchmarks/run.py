@@ -42,6 +42,10 @@ def main() -> None:
     args = ap.parse_args()
     fast = not args.full
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     from benchmarks import (
         appxC_heuristic,
         fig7_drift,

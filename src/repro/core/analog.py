@@ -195,16 +195,10 @@ def analog_matmul(
                 w_exec = engine_lib.resample_read(r_key, read_buf).astype(
                     w.dtype
                 )
-        x_q = quant_lib.dac_quantize(x, r_adc, ctx.gain_s, w_max, spec, None)
-        x_q = x_q.astype(out_dtype)
-        scale = 1.0 if out_scale is None else out_scale
-        return engine_lib.execute_mvm(
-            x_q,
-            w_exec.astype(x_q.dtype),
-            r_adc,
-            plan,
-            out_scale=scale,
-        ).astype(out_dtype)
+        return engine_lib.execute_programmed(
+            x, w_exec, r_adc, ctx.gain_s, w_max, plan,
+            out_scale=1.0 if out_scale is None else out_scale,
+        )
 
     if cfg.mode == PCM_INFER:
         w_key = ctx.next_key()

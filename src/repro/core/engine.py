@@ -216,8 +216,12 @@ def tile_matmul_quant(
     """
     k = w.shape[0]
     acc_dtype = jnp.float32
+    # f32 operands mean f32 products: a TPU's default precision would round
+    # them to bf16 on the MXU, which moves ADC codes (``HIGHEST`` is a no-op
+    # for bf16 operands and on the CPU)
+    hi = jax.lax.Precision.HIGHEST
     if not per_tile_adc or k <= tile_rows:
-        y = jnp.matmul(x, w, preferred_element_type=acc_dtype)
+        y = jnp.matmul(x, w, preferred_element_type=acc_dtype, precision=hi)
         y = quant_lib.adc_quantize(y, r_adc, spec, qn_key)
         return (y * out_scale).astype(x.dtype)
 
@@ -230,7 +234,8 @@ def tile_matmul_quant(
     wt = w.reshape(n_tiles, tile_rows, w.shape[-1])
     # (..., T, rows) x (T, rows, N) -> (..., T, N): one MVM per physical tile.
     y_tiles = jnp.einsum(
-        "...tk,tkn->...tn", xt, wt, preferred_element_type=acc_dtype
+        "...tk,tkn->...tn", xt, wt, preferred_element_type=acc_dtype,
+        precision=hi,
     )
     y_tiles = quant_lib.adc_quantize(y_tiles, r_adc, spec, qn_key)
     # per-tile quantized partials are grid values: store at compute dtype.
@@ -285,6 +290,32 @@ def execute_mvm(
         qn_key,
         out_scale,
     )
+
+
+def execute_programmed(
+    x: Array,
+    w: Array,
+    r_adc: Array,
+    gain_s: Array,
+    w_max: Array,
+    plan: ExecutionPlan,
+    out_scale: Array | float = 1.0,
+) -> Array:
+    """Execute phase of a programmed layer: DAC -> crossbar -> ADC -> GDC.
+
+    The crossbar sees DAC levels and PCM effective weights, so the chain
+    runs in f32 whatever the model's activation dtype; only the result is
+    cast back to ``x.dtype``. The per-layer path (``analog.analog_matmul``)
+    and the fused decode grid both execute through here.
+    """
+    x_q = quant_lib.dac_quantize(x, r_adc, gain_s, w_max, plan.spec, None)
+    return execute_mvm(
+        x_q.astype(jnp.float32),
+        w.astype(jnp.float32),
+        r_adc,
+        plan,
+        out_scale=out_scale,
+    ).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

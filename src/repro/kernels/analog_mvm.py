@@ -68,6 +68,8 @@ def _kernel(
         x_q,
         w_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
+        # f32 products, as the jnp oracle computes them
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     if per_tile_adc:

@@ -31,15 +31,18 @@ This module executes the ENTIRE programmed decode step as ONE
   layers.
 
 Bit-exactness contract: the kernel body calls the SAME library ops as the
-per-layer path (``quant.dac_quantize``, ``engine.tile_matmul_quant``,
-``common.rmsnorm_apply``/``rope``, ``attention.decode_attention``) at the
+per-layer path (``engine.execute_programmed``, ``common.rmsnorm_apply``/
+``rope``, ``attention.decode_attention``) at the
 same shapes and in the same order, and the KV write is a positional select
 of identical values -- so in interpret mode (every non-TPU host) the ADC
 codes are bit-identical to ``lm_forward``'s unfused decode, which the
 tests pin down exactly. On a TPU host (``jax.default_backend() == "tpu"``)
-the plan flips ``interpret=False`` and the same grid lowers natively; the
->= 1.3x tokens/s claim of the ``decode_step_fused`` bench row applies
-there (off-TPU the row is a parity/launch-count check only).
+the plan flips ``interpret=False``, and Mosaic refuses the grid: the
+in-kernel ``attention.decode_attention`` einsum has two batch dims ("Up to
+1 batch dim supported"), and at published widths the whole-layer weight
+blocks would not fit VMEM either (``tests/test_tpu_compile.py`` pins the
+refusal). The >= 1.3x tokens/s claim of the ``decode_step_fused`` bench
+row has therefore never been measured.
 
 Per-MVM read-noise resampling (``resample_read_noise`` programs executed
 with an RNG) re-draws the effective weight stacks OUTSIDE the kernel with
@@ -59,7 +62,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import engine as engine_lib
-from repro.core import quant as quant_lib
 from repro.models import attention as attn_lib
 from repro.models.common import (
     ModelConfig,
@@ -140,8 +142,8 @@ def _decode_kernel(
     tab_ref,  # (L+1, 7, 3) f32 scalar-prefetch: [r_adc, w_max, out_scale]
     h0_ref,  # (B, 1, D) embedded token (grid-constant)
     lens_ref,  # (B, 1) int32 per-slot positions (grid-constant)
-    n1_ref,  # (1, D) layer g's norm1 scale
-    n2_ref,  # (1, D) layer g's norm2 scale
+    n1_ref,  # (1, 1, D) layer g's norm1 scale
+    n2_ref,  # (1, 1, D) layer g's norm2 scale
     wq_ref,  # (1, D, nh*hd) layer g's projection weights ...
     wk_ref,
     wv_ref,
@@ -173,21 +175,10 @@ def _decode_kernel(
         # ADC requant at the plan's static bitwidth -> GDC out_scale. Same
         # library calls as analog.analog_matmul's pcm_programmed execute,
         # so the codes are bit-identical to the per-layer path.
-        r_adc = tab_ref[row, p_idx, 0]
-        w_max = tab_ref[row, p_idx, 1]
-        out_scale = tab_ref[row, p_idx, 2]
-        x_q = quant_lib.dac_quantize(h, r_adc, gain_s, w_max, pplan.spec, None)
-        x_q = x_q.astype(h.dtype)
-        return engine_lib.tile_matmul_quant(
-            x_q,
-            w.astype(x_q.dtype),
-            r_adc,
-            pplan.spec,
-            pplan.tile_rows,
-            pplan.per_tile_adc,
-            None,
-            out_scale,
-        ).astype(h.dtype)
+        return engine_lib.execute_programmed(
+            h, w, tab_ref[row, p_idx, 0], gain_s, tab_ref[row, p_idx, 1],
+            pplan, out_scale=tab_ref[row, p_idx, 2],
+        )
 
     @pl.when(g < n_groups)
     def _layer():
@@ -339,9 +330,11 @@ def fused_decode_step(
     stacks, w_head = _resampled_stacks(params, analog_cfg, rng)
     tab = _scalar_table(params, n_groups)
     block = params.blocks[0]
+    # (L, 1, D): the block's last two dims then equal the array's, which
+    # Mosaic requires of a (1, D) slice (second-minor 1 is not 8-aligned)
     ones_ld = jnp.ones((n_groups, d), jnp.float32)
-    n1 = block["norm1"].get("scale", ones_ld)
-    n2 = block["norm2"].get("scale", ones_ld)
+    n1 = block["norm1"].get("scale", ones_ld)[:, None, :]
+    n2 = block["norm2"].get("scale", ones_ld)[:, None, :]
     fin = params.final_norm.get(
         "scale", jnp.ones((d,), jnp.float32)
     )[None, :]
@@ -363,8 +356,8 @@ def fused_decode_step(
         in_specs=[
             pl.BlockSpec((b, 1, d), _const(0, 0, 0)),  # h0
             pl.BlockSpec((b, 1), _const(0, 0)),  # lens
-            pl.BlockSpec((1, d), _at_layer(1)),  # norm1 scale
-            pl.BlockSpec((1, d), _at_layer(1)),  # norm2 scale
+            pl.BlockSpec((1, 1, d), _at_layer(2)),  # norm1 scale
+            pl.BlockSpec((1, 1, d), _at_layer(2)),  # norm2 scale
         ]
         + [
             pl.BlockSpec((1,) + s.shape[1:], _at_layer(len(s.shape) - 1))

@@ -162,7 +162,7 @@ def run_cell(
                 donate_argnums=(2,),
             )
 
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()

@@ -4,6 +4,17 @@ module must not touch jax device initialisation)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """A mesh whose axes GSPMD shards (``Auto``).
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which put shardings in
+    the array types: the model code, written for GSPMD propagation, then
+    fails to trace (scan carries and gathers disagree on types).
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,14 +26,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 2):
     """Tiny mesh over however many (CPU) devices exist -- used by tests."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_serving_mesh(model: int | None = None):
@@ -38,4 +49,4 @@ def make_serving_mesh(model: int | None = None):
     model = n if model is None else max(1, min(model, n))
     while n % model:  # e.g. 8 devices, --mesh-model 3
         model -= 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

@@ -2,7 +2,9 @@
 
 ``python -m repro.launch.serve --arch tinyllama-1.1b --tokens 32 --batch 4``
 
-Runs a (reduced-config) model through the production serving flow. All
+Runs a model through the production serving flow: the arch's CPU smoke
+preset by default, or with ``--published`` its published config (every
+width as published, bf16 activations) cut to ``--n-layers`` layers. All
 serving goes through ``repro.serving.ServingEngine`` -- one jitted decode
 over a slot-based KV cache -- in one of two shapes:
 
@@ -71,6 +73,7 @@ and prints a greppable ``async fleet:`` throughput line.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -84,9 +87,12 @@ from repro.core import pcm as pcm_lib
 from repro.core.analog import AnalogConfig
 from repro.core.engine import DriftSchedule
 from repro.core.quant import SUPPORTED_B_ADC
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
+from repro.launch import sharding as shd
 from repro.launch import steps
 from repro.models import lm
+from repro.models.common import ModelConfig
 from repro.serving import (
     AsyncConfig,
     AsyncFleetRouter,
@@ -127,6 +133,17 @@ def trace_prompt_buckets(prompt_len: int) -> tuple[int, ...]:
     return tuple(sorted({max(1, (prompt_len * k) // 4) for k in (1, 2, 3, 4)}))
 
 
+def request_trace(args, cfg: ModelConfig) -> list[Request]:
+    """The ``--request-trace`` workload (seeded: the same args give the
+    same prompts, budgets and arrivals)."""
+    return poisson_trace(
+        jax.random.PRNGKey(7), args.request_trace,
+        vocab=cfg.vocab, rate=args.arrival_rate,
+        prompt_lens=trace_prompt_buckets(args.prompt_len),
+        new_tokens=(max(1, min(8, args.tokens)), args.tokens),
+    )
+
+
 def build_parser() -> argparse.ArgumentParser:
     """Serving CLI, grouped by subsystem (the groups mirror the config
     surfaces: serving -> ServingConfig, paging -> its paged fields,
@@ -134,6 +151,12 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b",
                     choices=sorted(configs.LM_ARCHS))
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch's published config (every width "
+                         "as published) instead of its CPU smoke preset")
+    ap.add_argument("--n-layers", type=int, default=None, metavar="N",
+                    help="cut a --published config to its first N layers "
+                         "(one pipeline stage of a deeper deployment)")
 
     g = ap.add_argument_group(
         "serving", "workload shape and the request-level engine")
@@ -150,6 +173,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "--request-trace (default: all queued at t=0)")
     g.add_argument("--no-ref-check", action="store_true",
                    help="skip the digital-reference accuracy counters")
+    g.add_argument("--record-logits", action="store_true",
+                   help="keep every emitted position's logits on its "
+                        "request record (host memory: vocab floats per "
+                        "token; for checks against a reference)")
 
     g = ap.add_argument_group(
         "paging", "paged KV cache + bucketed prefill (over --request-trace)")
@@ -240,8 +267,27 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def model_config(args) -> ModelConfig:
+    """The served model: the smoke preset, or the published config cut to
+    ``--n-layers``. Validation and serving both build it here."""
+    if not args.published:
+        return configs.get_smoke(args.arch)
+    cfg = configs.get(args.arch)
+    if args.n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
+    return cfg
+
+
 def validate_args(ap: argparse.ArgumentParser, args) -> None:
     """Reject mutually-inconsistent flag combinations with clear errors."""
+    if args.n_layers is not None:
+        if not args.published:
+            ap.error("--n-layers cuts a --published config (the smoke "
+                     "preset fixes its own depth)")
+        full = configs.get(args.arch).n_layers
+        if not 1 <= args.n_layers <= full:
+            ap.error(f"--n-layers must be in [1, {full}] for {args.arch}")
+    cfg = model_config(args)
     if args.per_call and not args.analog:
         ap.error("--per-call only qualifies --analog (pass both)")
     if args.load_program and args.per_call:
@@ -278,7 +324,7 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
     if args.request_trace is not None and args.request_trace < 1:
         ap.error("--request-trace needs at least one request")
     if args.request_trace is not None:
-        frontend = configs.get_smoke(args.arch).frontend
+        frontend = cfg.frontend
         if frontend in ("audio_frames", "vision_patches"):
             ap.error(f"--request-trace serves token prompts; the "
                      f"{frontend} frontend ({args.arch}) needs the "
@@ -291,7 +337,7 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
     if args.kv_page_size is not None and args.kv_page_size < 1:
         ap.error("--kv-page-size must be >= 1")
     if args.kv_page_size is not None:
-        family = configs.get_smoke(args.arch).family
+        family = cfg.family
         if family in ("ssm", "hybrid"):
             ap.error(f"--kv-page-size pages attention KV caches; the "
                      f"{family} family ({args.arch}) carries position-free "
@@ -319,13 +365,12 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
             ap.error("--fused-decode runs the decode step in one single-"
                      "device kernel; sharded serving keeps the per-layer "
                      "path")
-        fused_cfg = configs.get_smoke(args.arch)
-        if fused_cfg.family in ("ssm", "hybrid", "moe"):
+        if cfg.family in ("ssm", "hybrid", "moe"):
             ap.error(f"--fused-decode fuses the dense attention+FFN layer "
-                     f"walk; the {fused_cfg.family} family ({args.arch}) "
+                     f"walk; the {cfg.family} family ({args.arch}) "
                      "has recurrent or MoE blocks with no grid-step "
                      "lowering")
-        if fused_cfg.qkv_bias:
+        if cfg.qkv_bias:
             ap.error(f"--fused-decode executes bias-free projections; "
                      f"{args.arch} programs qkv biases the fused grid "
                      "cannot apply")
@@ -391,10 +436,13 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
               "will rewrite a different model", file=sys.stderr)
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default: ``sys.argv``), serve, print the summary and
+    return the serving report (a fleet's report under ``--fleet``)."""
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     validate_args(ap, args)
+    compile_cache.enable()
     schedule = None
     if args.drift_schedule:
         try:
@@ -409,7 +457,7 @@ def main() -> None:
         except ValueError as e:
             ap.error(str(e))
 
-    cfg = configs.get_smoke(args.arch)
+    cfg = model_config(args)
     if cfg.n_codebooks:
         # musicgen-style decoders emit one token per codebook per step; the
         # request-level engine drives a single token stream
@@ -432,17 +480,21 @@ def main() -> None:
     # one consumer per subkey: weight init, patch/token data, engine rng
     k_init, k_data, k_rng = jax.random.split(key, 3)
     params = lm.lm_init(k_init, cfg)
+    mesh = (mesh_lib.make_serving_mesh(args.mesh_model)
+            if args.mesh_model else None)
+    if mesh is not None:
+        # the chip's layout: left where lm_init put them, the source weights
+        # would hold a whole unsharded model on device 0 for the run
+        params = jax.device_put(
+            params, shd.program_shardings(params, mesh, cfg)
+        )
     # pre-programming weights: the digital reference for the accuracy
     # counters AND the source the refresh policy reprograms the chip from
     src_params = ref_params = params
 
-    mesh = (mesh_lib.make_serving_mesh(args.mesh_model)
-            if args.mesh_model else None)
     program = None
     if args.load_program is not None:
         t0 = time.time()
-        from repro.launch import sharding as shd
-
         program = store.load_program(
             args.load_program, params_like=params,
             shardings=shd.program_shardings(params, mesh, cfg)
@@ -479,6 +531,8 @@ def main() -> None:
             params, acfg, jax.random.PRNGKey(42), mesh=mesh, model_cfg=cfg,
             b_adc_overrides=overrides,
         )
+        # dispatch is asynchronous: time the programming, not its enqueue
+        jax.block_until_ready(program.params)
         where = f"on {mesh.devices.size}-device mesh " if mesh else ""
         mixed = f" with {len(overrides)} bitwidth overrides" if overrides else ""
         print(f"programmed {program.n_layers} analog layers once {where}"
@@ -493,8 +547,6 @@ def main() -> None:
             path = store.save_program(args.save_program, program)
             print(f"saved programmed chip artifact to {path}")
     if args.use_kernel:
-        import dataclasses
-
         # per-layer bits travel in the params (shape-encoded b_adc_buf), so
         # flipping the backend needs no recompile of the program itself
         acfg = dataclasses.replace(
@@ -529,6 +581,7 @@ def main() -> None:
         ),
         ref_check=not args.no_ref_check,
         fused_decode=args.fused_decode,
+        record_logits=args.record_logits,
     )
     served = None
     if fleet_n is None:
@@ -559,12 +612,7 @@ def main() -> None:
         # Continuous batching: variable-length requests through the slot
         # scheduler; with a --drift-schedule the chip ages (and refreshes)
         # BETWEEN decode steps of this single run via the DriftPolicy.
-        trace = poisson_trace(
-            jax.random.PRNGKey(7), args.request_trace,
-            vocab=cfg.vocab, rate=args.arrival_rate,
-            prompt_lens=trace_prompt_buckets(s),
-            new_tokens=(max(1, min(8, args.tokens)), args.tokens),
-        )
+        trace = request_trace(args, cfg)
         if cfg.family == "moe":
             print("warning: MoE capacity routing pools tokens across the "
                   "decode batch, so continuous-batching generations are "
@@ -623,7 +671,7 @@ def main() -> None:
             longest = max(freport.records, key=lambda r: r.n_new)
             print("generated token ids (longest request):",
                   longest.tokens[: min(16, longest.n_new)].tolist())
-            return
+            return freport
         policy = None
         if schedule is not None:
             est_steps = sum(r.max_new_tokens for r in trace) // max(b, 1)
@@ -654,7 +702,7 @@ def main() -> None:
         longest = max(report.records, key=lambda r: r.n_new)
         print("generated token ids (longest request):",
               longest.tokens[: min(16, longest.n_new)].tolist())
-        return
+        return report
 
     def rectangle_requests():
         toks = jax.random.randint(k_data, (b, s), 0, cfg.vocab)
@@ -723,6 +771,7 @@ def main() -> None:
     seq0 = m.tokens_of(0)
     print("generated token ids (first sequence):",
           seq0[: min(16, seq0.size)].tolist())
+    return m
 
 
 if __name__ == "__main__":

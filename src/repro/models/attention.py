@@ -204,8 +204,16 @@ def decode_attention(
     else:
         valid = pos[None, :] < limit
         s = jnp.where(valid[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return _gqa_values(p, cache.v).astype(q.dtype)
+    # normalize AFTER the PV product, as chunked_attention does: the bf16
+    # cast then rounds the same unnormalized probabilities in both paths,
+    # so a cached decode step reproduces the full forward's attention
+    # (normalizing first differs from it by ~2^-9 per probability in bf16)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    l = p.sum(axis=-1)  # (B, Kv, G, 1)
+    out = _gqa_values(p, cache.v)  # (B, 1, H, D) f32
+    b, kv, g, sq = l.shape
+    l = l.transpose(0, 3, 1, 2).reshape(b, sq, kv * g, 1)
+    return (out / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
 class PagedKVCache(NamedTuple):

@@ -44,14 +44,9 @@ def shard(x: Array, *names: Optional[str]) -> Array:
     """Annotate ``x`` with a sharding built from logical axis names."""
     if not _LOGICAL_RULES:
         return x
-    mesh = None
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-    except Exception:
-        mesh = None
-    if mesh is None or mesh.empty:
+    # the mesh entered with ``jax.set_mesh`` (empty outside one)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = P(*[_LOGICAL_RULES.get(n) if n else None for n in names])
     return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(mesh, spec))
@@ -178,7 +173,9 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
     """Rotary embeddings. x: (..., S, H, hd), positions: (..., S)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    # integer iota, then convert: Mosaic (the fused decode grid) has no
+    # float iota; the values are identical
+    freqs = theta ** (-jnp.arange(half).astype(jnp.float32) / half)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]

@@ -30,24 +30,13 @@ from repro.models.common import ModelConfig
 Array = jax.Array
 
 
-def _active_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
-
-
 def moe_apply_shardmap(
     params: dict, x: Array, ctx: AnalogCtx, cfg: ModelConfig
 ) -> Array:
     """x: (B, S, M) batch-sharded over the data axes; experts over model."""
-    mesh = _active_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    # the mesh entered with ``jax.set_mesh`` (empty outside one)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return moe_lib.moe_apply(params, x, ctx, cfg)
     n_model = mesh.shape["model"]
     e = cfg.n_experts

@@ -69,6 +69,12 @@ class ServingConfig:
         whose plans pass ``engine.build_fused_plan``; bit-identical to
         the per-layer decode. Does not compose with ``paged`` (the fused
         grid owns one stacked slot cache, not a page pool).
+    ``record_logits``
+        Keep the logits of every emitted position on the request's
+        :class:`~repro.serving.requests.RequestRecord` (``logits``, f32
+        ``(n_new, vocab)`` on the host): the served path's own output, for
+        checks against a reference forward. Costs one (slots, vocab) copy
+        to the host per decode step.
     """
 
     n_slots: int
@@ -80,6 +86,7 @@ class ServingConfig:
     prefill_batch: int = 4
     ref_check: bool = True
     fused_decode: bool = False
+    record_logits: bool = False
 
     def __post_init__(self):
         if self.n_slots < 1:

@@ -150,6 +150,8 @@ class _Slot:
     # worst-case growth -- see EngineRun.decode_step
     pages: Optional[list] = None
     reserve_left: int = 0
+    #: per-position logits rows (``ServingConfig.record_logits``)
+    logits: Optional[list] = None
 
 
 @dataclasses.dataclass
@@ -178,6 +180,9 @@ class ServeReport:
     peak_kv_bytes: int = 0
     #: paged mode: allocator high-water mark (pages), else 0
     peak_pages_in_use: int = 0
+    #: the engine that served the run: its ``program`` (the chip as the
+    #: run left it), ``acfg`` and ``params``. Holding a report holds them.
+    engine: Optional["ServingEngine"] = None
 
     @property
     def n_requests(self) -> int:
@@ -901,7 +906,8 @@ class EngineRun:
             self.t_prefill += self.now_fn() - t0
             self.slots[slot] = _Slot(
                 # repro-lint: disable=RL004 -- one sync per ADMISSION (not per decode tick): the first token must reach the host record
-                req, [int(tok0[0])], self.steps, self.now_fn() - self.t_start
+                req, [int(tok0[0])], self.steps, self.now_fn() - self.t_start,
+                logits=self._logits_rows(logits0, 0),
             )
             if self.on_token is not None:
                 self.on_token(req.rid, self.slots[slot].tokens[0])
@@ -975,6 +981,7 @@ class EngineRun:
                     req, [int(tokv[j])], self.steps,
                     self.now_fn() - self.t_start,
                     pages=pages, reserve_left=need - nbp_real,
+                    logits=self._logits_rows(logitsv, j),
                 )
                 if self.on_token is not None:
                     self.on_token(req.rid, self.slots[slot].tokens[0])
@@ -1020,8 +1027,14 @@ class EngineRun:
         self.steps += 1
         active = [i for i, s in enumerate(self.slots) if s is not None]
         self.slot_steps += len(active)
+        rows = (
+            np.asarray(logits, np.float32)
+            if eng.config.record_logits else None
+        )
         for i in active:
             self.slots[i].tokens.append(int(nxt_np[i]))
+            if rows is not None:
+                self.slots[i].logits.append(rows[i])
             if self.on_token is not None:
                 self.on_token(self.slots[i].req.rid, self.slots[i].tokens[-1])
             if eng._ref:
@@ -1042,6 +1055,12 @@ class EngineRun:
                 f"{self.n_active} live slots and "
                 f"{len(self.queue)} queued requests"
             )
+
+    def _logits_rows(self, logits, row: int) -> Optional[list]:
+        """A new slot's recorded logits: its first token's row, if kept."""
+        if not self.eng.config.record_logits:
+            return None
+        return [np.asarray(logits[row], np.float32)]
 
     def _count_decision(self, a_logits, r_logits, row: int) -> None:
         a, e = self.eng._count(a_logits, r_logits)
@@ -1101,6 +1120,7 @@ class EngineRun:
             admit_t=st.admit_t if first_t is None else first_t,
             finish_t=self.now_fn() - self.t_start,
             finished_by=by,
+            logits=None if st.logits is None else np.stack(st.logits),
         )
         self.records.append(rec)
         self._release_slot(i, st)
@@ -1216,4 +1236,5 @@ class EngineRun:
             peak_pages_in_use=(
                 self.allocator.peak_in_use if eng.paged else 0
             ),
+            engine=eng,
         )

@@ -76,6 +76,9 @@ class RequestRecord:
     admit_t: float  # seconds since run start
     finish_t: float
     finished_by: str  # "eos" | "max_tokens"
+    #: (n_new, vocab) f32 logits of every emitted position, row i the one
+    #: ``tokens[i]`` was taken from (``ServingConfig.record_logits``)
+    logits: Optional[np.ndarray] = None
 
     @property
     def latency_s(self) -> float:

@@ -28,7 +28,8 @@ from repro.models.common import set_logical_rules
 from repro.models import lm
 from repro.training import optim as optim_lib
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch import mesh as mesh_lib
+mesh = mesh_lib.make_host_mesh(model=2)  # (4, 2) over 8 devices
 cfg = configs.get_smoke("tinyllama-1.1b")
 set_logical_rules(shd.logical_rules(mesh, cfg))
 key = jax.random.PRNGKey(0)
@@ -56,7 +57,7 @@ acfg = AnalogConfig().train(eta=0.05)
 step = make_train_step(cfg, acfg, opt_cfg)
 jstep = jax.jit(step, in_shardings=(param_shards, opt_shards, batch_shards, rep),
                 out_shardings=(param_shards, opt_shards, rep))
-with mesh:
+with jax.set_mesh(mesh):
     params_s = jax.device_put(params, param_shards)
     opt_s = jax.device_put(opt_state, opt_shards)
     batch_s = jax.device_put(batch, batch_shards)

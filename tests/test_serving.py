@@ -241,7 +241,33 @@ BAD_ARGV = {
     "fused_decode_with_qkv_bias_arch": [
         "--analog", "--arch", "qwen2-72b", "--fused-decode"
     ],
+    "n_layers_without_published": ["--arch", "olmo-1b", "--n-layers", "2"],
+    "n_layers_zero": ["--arch", "olmo-1b", "--published", "--n-layers", "0"],
+    "n_layers_past_published_depth": [
+        "--arch", "olmo-1b", "--published", "--n-layers", "17"
+    ],
 }
+
+
+@pytest.mark.parametrize("argv,want_layers", [
+    (["--arch", "olmo-1b"], None),
+    (["--arch", "olmo-1b", "--published"], 16),
+    (["--arch", "olmo-1b", "--published", "--n-layers", "4"], 4),
+])
+def test_serve_model_config_published_depth_cut(argv, want_layers):
+    """``--published`` keeps every published width and only ``--n-layers``
+    cuts depth; without it the CPU smoke preset is served."""
+    from repro import configs
+    from repro.launch import serve
+
+    cfg = serve.model_config(serve.build_parser().parse_args(argv))
+    if want_layers is None:
+        assert cfg == configs.get_smoke("olmo-1b")
+        return
+    pub = configs.get("olmo-1b")
+    assert (cfg.d_model, cfg.n_heads, cfg.hd, cfg.d_ff, cfg.vocab,
+            cfg.dtype) == (2048, 16, 128, 8192, 50304, pub.dtype)
+    assert cfg.n_layers == want_layers
 
 
 @pytest.mark.parametrize("name", sorted(BAD_ARGV))

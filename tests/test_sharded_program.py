@@ -22,6 +22,7 @@ import pytest
 from repro.checkpoint import store
 from repro.core import engine
 from repro.core.analog import AnalogConfig, AnalogCtx
+from repro.launch import mesh as mesh_lib
 from repro.models import ModelConfig, lm_forward, lm_init
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -132,7 +133,7 @@ def test_moe_shardmap_programmed_parity_on_mesh():
     from repro.models import moe as moe_lib
     from repro.models.moe_shardmap import moe_apply_shardmap
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = mesh_lib.make_serving_mesh(4)
     cfg = ModelConfig(
         family="moe", n_experts=8, top_k=2, d_model=32, d_ff=64,
         capacity_factor=8.0, moe_groups=2, shared_expert=True,
@@ -145,7 +146,7 @@ def test_moe_shardmap_programmed_parity_on_mesh():
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
 
     y_einsum = moe_lib.moe_apply(node, x, ctx, cfg)
-    with mesh:
+    with jax.set_mesh(mesh):
         y_shardmap = moe_apply_shardmap(node, x, ctx, cfg)
     np.testing.assert_allclose(
         np.asarray(y_einsum), np.asarray(y_shardmap), rtol=1e-4, atol=1e-5
