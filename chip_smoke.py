@@ -4,11 +4,12 @@
     python chip_smoke.py               # one chip: phases a-d
     python chip_smoke.py --four-chips  # four chips: sharded vs one-chip
 
-Drives the system's main path once, through ``repro.launch.serve.main``, in
-this one process (a child would find the chip held). The model is olmo-1b
-at its published widths -- d_model 2048, 16 x 128 MHA heads, d_ff 8192,
-vocab 50304, bf16 activations -- cut to 4 of its 16 layers: one stage of a
-four-stage pipeline deployment, except that this chip also keeps the
+Drives the system's main path once, through the ``repro.launch.serve`` CLI
+(``serve.run``, the body of ``serve.main``), in this one process (a child
+would find the chip held). The model is olmo-1b at its published widths --
+d_model 2048, 16 x 128 MHA heads, d_ff 8192, vocab 50304, bf16 activations
+-- cut to 4 of its 16 layers: one stage of a four-stage pipeline
+deployment, except that this chip also keeps the
 embedding (the first stage's) and the head (the last stage's) so that one
 chip serves a whole model. Weights are random from a seed, programmed once
 into a simulated PCM chip with 8-bit ADCs, and served to an 8-request trace.
@@ -19,12 +20,13 @@ Phases on one chip:
      and every request retires; prints program, compile and peak memory;
   c  the served prefill-then-decode logits agree with a full-sequence
      forward of the same programmed params (no cache, no batching, f32
-     matmuls at ``highest``), and every programmed MVM's ADC codes agree
-     with a float64 reference -- tightly enough that bf16 analog weights
-     fail (both tolerances are stated, with their reasons, below);
+     matmuls at ``highest``), and every programmed layer, called as the
+     model calls it, agrees with a float64 reference -- tightly enough
+     that the same call with its MVM run in bf16 fails (both tolerances
+     are stated, with their reasons, below);
   d  the same trace through the native Pallas MVM kernel (every
      ``pallas_call`` on the path has ``interpret=False``) agrees with b,
-     and the kernel's ADC codes agree with the float64 reference.
+     and the kernel's layer outputs agree with the float64 reference.
 With ``--four-chips`` only: the trace served by a chip programmed over a
 four-device mesh (``--mesh-model 4``) agrees with the same chip programmed
 on device 0 alone -- bit-identical effective weights, logits within the
@@ -75,14 +77,19 @@ N_REQUESTS = 8
 #: Precision is checked per MVM instead (TOL_CODES), where no cascade runs.
 TOL_LOGITS = 0.1
 
-#: Largest share of ADC codes that may differ between the served MVM and a
-#: float64 NumPy reference, per programmed layer. The inputs are drawn on
-#: the DAC grid, so both see the same DAC codes; an f32 sum of at most 1024
-#: products then lies within ~1e-7 of the float64 one relative to its
-#: spread, and a code differs only at a boundary (share ~1e-5). bf16
-#: operands move every product by ~2^-9 of itself and flip over a tenth of
-#: the codes (0.107 and 0.139 at d_model 512 and 1024 on the CPU), which
-#: phase c checks on the chip with the analog weights rounded to bf16.
+#: Largest share of a programmed layer's outputs that may be off a float64
+#: NumPy reference by more than half an ADC step, the layer being called as
+#: the model calls it (``analog.linear_apply`` on activations in the model's
+#: dtype) and the reference rounded to that dtype as the device rounds its
+#: result. Both see the same DAC codes (activations on a DAC tie are
+#: zeroed); an f32 sum of at most 1024 products then lies within ~1e-7 of
+#: the float64 one relative to its spread, and a code differs only at a
+#: boundary: at most one output per layer, 2.4e-4 of 4096 at d_model 512
+#: in bf16 on the CPU. A bf16 MVM rounds every DAC level and weight to 8
+#: bits and moves 0.077 of the outputs there; so does the analog path run
+#: at the model's bf16. Phase c runs the same call on the layer's weights
+#: stored in bf16, which runs its MVM in bf16, as the control that must
+#: fail.
 TOL_CODES = 1e-3
 
 
@@ -204,10 +211,7 @@ def compare_served(name: str, got: dict, want: dict) -> float:
 
 
 def reference_mvm(x, w, r_adc, gain_s, w_max, out_scale, bits, tile_rows):
-    """DAC -> per-row-tile crossbar MVM -> ADC -> GDC in float64 NumPy.
-
-    Returns ADC codes (the output over one LSB times the GDC scale).
-    """
+    """DAC -> per-row-tile crossbar MVM -> ADC -> GDC in float64 NumPy."""
     import numpy as np
 
     def quant(v, r, b):
@@ -220,72 +224,77 @@ def reference_mvm(x, w, r_adc, gain_s, w_max, out_scale, bits, tile_rows):
     r_dac = abs(r_adc) * abs(gain_s) / (abs(w_max) + 1e-9)
     xq = quant(x, r_dac, bits + 1)
     y = sum(
-        quant(xq[:, t:t + tile_rows] @ w[t:t + tile_rows], r_adc, bits)
+        quant(xq[..., t:t + tile_rows] @ w[t:t + tile_rows], r_adc, bits)
         for t in range(0, w.shape[0], tile_rows)
     )
     return y * out_scale
 
 
-def code_mismatch(params, acfg, *, weights=None, rows: int = 8) -> float:
-    """Largest per-layer share of ADC codes where the served MVM differs
-    from :func:`reference_mvm` on the same random inputs.
+def code_mismatch(params, acfg, dtype, *, w_dtype=None, rows: int = 8):
+    """Largest per-layer share of outputs where a programmed layer, run
+    through the model's own layer call, is off the float64 reference.
 
     Every programmed layer (each member of a stacked group) runs through
-    ``engine.execute_programmed`` under ``acfg`` -- the jnp oracle, or the
-    Pallas kernel when ``acfg.use_kernel`` -- jitted at the default matmul
-    precision, as serving runs it. ``weights`` maps each weight before the
-    device sees it (the bf16 control); the reference keeps the f32 ones.
+    ``analog.linear_apply`` under ``acfg`` -- the jnp oracle, or the Pallas
+    kernel when ``acfg.use_kernel`` -- jitted at the default matmul
+    precision, on random activations in the model's ``dtype``, as serving
+    runs it. Its output, in that dtype, is compared with
+    :func:`reference_mvm` of the same activations rounded to that dtype;
+    an output is off where the two differ by more than half an ADC step.
+    ``w_dtype`` stores each layer's effective weights in that dtype first,
+    so that its MVM runs in it (the control); the reference keeps them f32.
     """
-    import functools
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import engine
+    from repro.core import analog, engine
+
+    @jax.jit
+    def run(layer, x, gain_s):
+        ctx = analog.AnalogCtx(cfg=acfg, gain_s=gain_s)
+        return analog.linear_apply(layer, x, ctx)
 
     gain_s = params.gain_s
-
-    @functools.partial(jax.jit, static_argnames="plan")
-    def run(x, w, r_adc, w_max, out_scale, plan):
-        return engine.execute_programmed(
-            x, w, r_adc, gain_s, w_max, plan, out_scale=out_scale
-        )
-
     rng = np.random.default_rng(0)
     worst = 0.0
     for _path, node in analog_layers(params):
-        w_all = node["w"]
-        bits = engine.bits_of(node.get("b_adc_buf")) or acfg.b_adc
-        plan = engine.plan_for(
-            acfg, int(w_all.shape[-2]), int(w_all.shape[-1]), b_adc=bits
-        )
-        stack = w_all.shape[:-2]
-        for i in np.ndindex(*stack):
-            w = w_all[i]
-            r_adc = node["r_adc"][i]
-            w_max = node["w_clip_buf"][i][..., 1]
-            out_scale = node["out_scale_buf"][i]
-            # activations on the DAC grid: the device re-quantizes them
-            # to the same codes whatever its f32 division rounds to
-            r_dac = abs(float(r_adc)) * abs(float(gain_s)) / (
-                abs(float(w_max)) + 1e-9
-            )
-            step = (r_dac + 1e-9) / (2 ** bits - 1)
-            k = np.round(np.clip(
-                rng.standard_normal((rows, w.shape[0])), -r_dac, r_dac
-            ) / step)
-            x = (k * step).astype(np.float32)
-            w_dev = w if weights is None else weights(w)
-            y = np.asarray(run(jnp.asarray(x), w_dev, r_adc, w_max,
-                               out_scale, plan), np.float64)
-            lsb = (abs(float(r_adc)) + 1e-9) / (2 ** (bits - 1) - 1)
-            scale = lsb * float(out_scale)
+        for i in np.ndindex(*node["w"].shape[:-2]):
+            # the MVM alone: a bias is added after the ADC, in the digital
+            # domain (none in the chip-smoke model)
+            layer = {
+                k: v for k, v in jax.tree.map(lambda a: a[i], node).items()
+                if k != "b"
+            }
+            w = np.asarray(layer["w"])
+            if w_dtype is not None:
+                layer["w"] = layer["w"].astype(w_dtype)
+            bits = engine.bits_of(layer.get("b_adc_buf")) or acfg.b_adc
+            r_adc = abs(float(layer["r_adc"]))
+            w_max = float(layer["w_clip_buf"][1])
+            out_scale = float(layer["out_scale_buf"])
+            r_dac = r_adc * abs(float(gain_s)) / (abs(w_max) + 1e-9)
+            # (batch, positions, features), as the model calls the layer
+            x = rng.standard_normal((1, rows, w.shape[0]))
+            x = np.asarray(jnp.asarray(x * r_dac / 2, dtype), np.float64)
+            # no activation on a DAC tie, where the code would depend on
+            # how the device rounds a division (a TPU divides through a
+            # reciprocal): bf16 activations sit on ties often when the DAC
+            # step is near a power of two, as 1/255 is at unit ranges
+            frac = np.abs(x / ((r_dac + 1e-9) / (2**bits - 1))) % 1.0
+            x[np.abs(frac - 0.5) < 1e-3] = 0.0
+            y = np.asarray(run(layer, jnp.asarray(x, dtype), gain_s),
+                           np.float64)
             want = reference_mvm(
-                x, np.asarray(w), float(r_adc), float(gain_s),
-                float(w_max), float(out_scale), bits, plan.tile_rows,
+                x, w, r_adc,
+                float(gain_s), w_max, out_scale, bits, acfg.tile_rows,
             )
-            share = float(np.mean(np.abs(y - want) / scale > 0.5))
+            # rounded as the device rounds its f32 result to the model dtype
+            want = np.asarray(
+                jnp.asarray(want, jnp.float32).astype(dtype), np.float64
+            )
+            scale = (r_adc + 1e-9) / (2 ** (bits - 1) - 1) * out_scale
+            share = float(np.mean(np.abs(y - want) > 0.5 * scale))
             worst = max(worst, share)
     return worst
 
@@ -301,13 +310,17 @@ def served_logits(report) -> dict:
 
 
 def serve_phase(model_args, extra=()):
-    """Phase b: serve the trace through ``serve.main`` in this process."""
+    """Phase b: serve the trace through the ``serve`` CLI in this process.
+
+    Returns ``(report, engine, argv)``: the engine holds the programmed
+    chip that served.
+    """
     from repro.launch import serve
 
     argv = [*model_args, *TRACE, *extra]
     _log("serve " + " ".join(argv))
     t0 = time.perf_counter()
-    report = serve.main(argv)
+    report, eng = serve.run(argv)
     wall = time.perf_counter() - t0
     if report.n_requests != N_REQUESTS:
         raise AssertionError(
@@ -322,13 +335,13 @@ def serve_phase(model_args, extra=()):
            for r in report.records):
         raise AssertionError("a request record lacks its logits")
     _log(f"served {report.n_requests} requests, {report.n_generated} "
-         f"tokens: serve.main wall={wall:.3f}s (run {report.wall:.3f}s)")
-    return report, argv
+         f"tokens: serve.run wall={wall:.3f}s (run {report.wall:.3f}s)")
+    return report, eng, argv
 
 
-def reference_phase(report, argv) -> dict:
+def reference_phase(report, eng, argv) -> dict:
     """Phase c: served logits vs a full-sequence forward on the chip, and
-    every programmed MVM vs a float64 reference."""
+    every programmed layer vs a float64 reference."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -339,7 +352,6 @@ def reference_phase(report, argv) -> dict:
     args = serve.build_parser().parse_args(argv)
     cfg = serve.model_config(args)
     prompts = {r.rid: r.prompt for r in serve.request_trace(args, cfg)}
-    eng = report.engine
     if eng.acfg.use_kernel:
         raise ValueError("the reference replays the jnp execute path")
 
@@ -371,17 +383,18 @@ def reference_phase(report, argv) -> dict:
         "median_rel_err": float(np.median(err)),
         "shifted_median_rel_err": float(np.median(shifted)),
     }
-    out["code_mismatch"] = code_mismatch(eng.params, eng.acfg)
+    out["code_mismatch"] = code_mismatch(eng.params, eng.acfg, cfg.dtype)
+    # control: the same layer call with its MVM run in bf16
     out["bf16_code_mismatch"] = code_mismatch(
-        eng.params, eng.acfg, weights=lambda w: w.astype(jnp.bfloat16)
+        eng.params, eng.acfg, cfg.dtype, w_dtype=jnp.bfloat16
     )
     _log(f"phase c: served vs full forward over {out['positions']} "
          f"positions: max rel_err={out['max_rel_err']!r} median="
          f"{out['median_rel_err']!r} (tolerance {TOL_LOGITS}); one position "
          f"off: median rel_err={out['shifted_median_rel_err']!r}")
-    _log(f"phase c: ADC codes vs float64, worst layer: "
+    _log(f"phase c: layer outputs vs float64, worst layer: "
          f"{out['code_mismatch']!r} differ (tolerance {TOL_CODES}); with "
-         f"bf16 analog weights {out['bf16_code_mismatch']!r}")
+         f"the MVM in bf16 {out['bf16_code_mismatch']!r}")
     if not out["max_rel_err"] <= TOL_LOGITS:
         raise AssertionError(f"phase c: served logits off the reference: {out}")
     if not out["shifted_median_rel_err"] > TOL_LOGITS:
@@ -389,11 +402,11 @@ def reference_phase(report, argv) -> dict:
             f"phase c: TOL_LOGITS cannot tell adjacent positions apart: {out}"
         )
     if not out["code_mismatch"] <= TOL_CODES:
-        raise AssertionError(f"phase c: ADC codes off the reference: {out}")
+        raise AssertionError(f"phase c: layers off the reference: {out}")
     if not out["bf16_code_mismatch"] > TOL_CODES:
         raise AssertionError(
-            f"phase c: TOL_CODES cannot tell bf16 analog weights from f32 "
-            f"ones: {out}"
+            f"phase c: TOL_CODES cannot tell a bf16 MVM from an f32 one: "
+            f"{out}"
         )
     return out
 
@@ -406,10 +419,9 @@ def kernel_phase(model_args, base: dict, *, native: bool = True) -> float:
     from repro.launch import serve
     from repro.models.lm import lm_forward
 
-    report, argv = serve_phase(
+    report, eng, argv = serve_phase(
         model_args, extra=("--use-kernel", "--no-ref-check")
     )
-    eng = report.engine
     cfg = serve.model_config(serve.build_parser().parse_args(argv))
     flags = [
         bool(p["interpret"]) for p in pallas_calls(
@@ -424,9 +436,9 @@ def kernel_phase(model_args, base: dict, *, native: bool = True) -> float:
             f"phase d: want every pallas_call with interpret={not native}, "
             f"got {flags}"
         )
-    codes = code_mismatch(eng.params, eng.acfg)
-    _log(f"phase d: kernel ADC codes vs float64, worst layer: {codes!r} "
-         f"differ (tolerance {TOL_CODES})")
+    codes = code_mismatch(eng.params, eng.acfg, cfg.dtype)
+    _log(f"phase d: kernel layer outputs vs float64, worst layer: "
+         f"{codes!r} differ (tolerance {TOL_CODES})")
     if not codes <= TOL_CODES:
         raise AssertionError(f"phase d: kernel codes off: {codes!r}")
     return compare_served("phase d: kernel vs jnp oracle",
@@ -441,10 +453,9 @@ def four_chip_phase(model_args, n_chips: int = 4) -> dict:
     # the digital reference counters stay off: they would add compiles and
     # nothing to this comparison
     extra = ("--no-ref-check",)
-    report, _ = serve_phase(
+    report, eng, _ = serve_phase(
         model_args, extra=extra + ("--mesh-model", str(n_chips))
     )
-    eng = report.engine
     spans = {}
     for path, node in analog_layers(eng.params):
         sh = node["w"].sharding
@@ -474,9 +485,9 @@ def four_chip_phase(model_args, n_chips: int = 4) -> dict:
     del report, eng
     gc.collect()
 
-    report, _ = serve_phase(model_args, extra=extra)
+    report, eng, _ = serve_phase(model_args, extra=extra)
     n_diff = 0
-    for path, node in analog_layers(report.engine.params):
+    for path, node in analog_layers(eng.params):
         if not np.array_equal(np.asarray(node["w"]), w_sharded[path]):
             n_diff += 1
             _log(f"  w_eff differs at {path}")
@@ -517,7 +528,7 @@ def main(argv=None) -> int:
              f"backend compile {clock.seconds:.3f}s")
     else:
         t0, c0 = time.perf_counter(), clock.seconds
-        report, argv_b = serve_phase(PUBLISHED)
+        report, eng, argv_b = serve_phase(PUBLISHED)
         stats = jax.devices()[0].memory_stats() or {}
         _log(f"phase b: {time.perf_counter() - t0:.3f}s, backend compile "
              f"{clock.seconds - c0:.3f}s, peak_bytes_in_use="
@@ -526,11 +537,11 @@ def main(argv=None) -> int:
         _log(report.summary())
 
         t0, c0 = time.perf_counter(), clock.seconds
-        reference_phase(report, argv_b)
+        reference_phase(report, eng, argv_b)
         _log(f"phase c: {time.perf_counter() - t0:.3f}s, backend compile "
              f"{clock.seconds - c0:.3f}s")
         base = served_logits(report)
-        del report
+        del report, eng
         gc.collect()
 
         t0, c0 = time.perf_counter(), clock.seconds

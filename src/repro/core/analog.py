@@ -209,15 +209,9 @@ def analog_matmul(
         w_eff, gdc = pcm_lib.simulate_weights(
             w_key, w_c.astype(jnp.float32), cfg.t_seconds, cfg.pcm
         )
-        x_q = quant_lib.dac_quantize(x, r_adc, ctx.gain_s, w_max, spec, None)
-        x_q = x_q.astype(out_dtype)
-        return engine_lib.execute_mvm(
-            x_q,
-            w_eff.astype(x_q.dtype),
-            r_adc,
-            plan,
-            out_scale=gdc,
-        ).astype(out_dtype)
+        return engine_lib.execute_programmed(
+            x, w_eff, r_adc, ctx.gain_s, w_max, plan, out_scale=gdc
+        )
 
     raise ValueError(f"unknown analog mode: {cfg.mode}")
 

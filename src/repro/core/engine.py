@@ -301,17 +301,19 @@ def execute_programmed(
     plan: ExecutionPlan,
     out_scale: Array | float = 1.0,
 ) -> Array:
-    """Execute phase of a programmed layer: DAC -> crossbar -> ADC -> GDC.
+    """Execute phase of a deployed layer: DAC -> crossbar -> ADC -> GDC.
 
-    The crossbar sees DAC levels and PCM effective weights, so the chain
-    runs in f32 whatever the model's activation dtype; only the result is
-    cast back to ``x.dtype``. The per-layer path (``analog.analog_matmul``)
-    and the fused decode grid both execute through here.
+    The crossbar MVM runs in the dtype of the effective weights ``w`` --
+    f32 in every program, as analog weights are f32 masters -- whatever the
+    model's activation dtype: the DAC levels are cast to it, and only the
+    result is cast back to ``x.dtype``. Per-call (``pcm_infer``) and
+    programmed layers (``analog.analog_matmul``) and the fused decode grid
+    all execute through here.
     """
     x_q = quant_lib.dac_quantize(x, r_adc, gain_s, w_max, plan.spec, None)
     return execute_mvm(
-        x_q.astype(jnp.float32),
-        w.astype(jnp.float32),
+        x_q.astype(w.dtype),
+        w,
         r_adc,
         plan,
         out_scale=out_scale,

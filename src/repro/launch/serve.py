@@ -439,6 +439,13 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
 def main(argv=None):
     """Parse ``argv`` (default: ``sys.argv``), serve, print the summary and
     return the serving report (a fleet's report under ``--fleet``)."""
+    return run(argv)[0]
+
+
+def run(argv=None):
+    """:func:`main`, returning ``(report, engine)``: the
+    :class:`ServingEngine` that served (``None`` under ``--fleet``) holds
+    the programmed chip as the run left it."""
     ap = build_parser()
     args = ap.parse_args(argv)
     validate_args(ap, args)
@@ -671,7 +678,7 @@ def main(argv=None):
             longest = max(freport.records, key=lambda r: r.n_new)
             print("generated token ids (longest request):",
                   longest.tokens[: min(16, longest.n_new)].tolist())
-            return freport
+            return freport, None
         policy = None
         if schedule is not None:
             est_steps = sum(r.max_new_tokens for r in trace) // max(b, 1)
@@ -702,7 +709,7 @@ def main(argv=None):
         longest = max(report.records, key=lambda r: r.n_new)
         print("generated token ids (longest request):",
               longest.tokens[: min(16, longest.n_new)].tolist())
-        return report
+        return report, served
 
     def rectangle_requests():
         toks = jax.random.randint(k_data, (b, s), 0, cfg.vocab)
@@ -771,7 +778,7 @@ def main(argv=None):
     seq0 = m.tokens_of(0)
     print("generated token ids (first sequence):",
           seq0[: min(16, seq0.size)].tolist())
-    return m
+    return m, served
 
 
 if __name__ == "__main__":

@@ -180,9 +180,6 @@ class ServeReport:
     peak_kv_bytes: int = 0
     #: paged mode: allocator high-water mark (pages), else 0
     peak_pages_in_use: int = 0
-    #: the engine that served the run: its ``program`` (the chip as the
-    #: run left it), ``acfg`` and ``params``. Holding a report holds them.
-    engine: Optional["ServingEngine"] = None
 
     @property
     def n_requests(self) -> int:
@@ -1236,5 +1233,4 @@ class EngineRun:
             peak_pages_in_use=(
                 self.allocator.peak_in_use if eng.paged else 0
             ),
-            engine=eng,
         )

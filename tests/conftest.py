@@ -9,13 +9,18 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+# Entry points under test (``serve.main``) point JAX at a persistent
+# compilation cache; tests compile afresh, in every run and every worker.
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 # --------------------------------------------------------------- retraces
 #
 # One listener, registered once per process (jax.monitoring has no
 # unregister), counting XLA compilations: the backend_compile event fires
-# exactly once per new trace/compile and never on a jit cache hit.
+# exactly once per new trace/compile and never on a jit cache hit. It times
+# the persistent-cache lookup too, so a program read back from that cache
+# counts as well (tests/test_retrace_fixture.py).
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _compile_count = [0]
 

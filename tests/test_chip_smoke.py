@@ -1,7 +1,7 @@
 """chip_smoke.py's phases on the CPU, at the olmo-1b smoke preset.
 
 The script runs the same phase functions at olmo-1b's published widths on a
-TPU; here they run through the same ``serve.main`` path at d_model 64 with
+TPU; here they run through the same ``serve`` CLI path at d_model 64 with
 the Pallas kernels in interpret mode, and the script's own entry point must
 refuse the CPU.
 """
@@ -29,12 +29,12 @@ def served():
 
 
 def test_serve_phase_programs_once_and_retires_every_request(served):
-    report, argv = served
+    report, eng, argv = served
     assert "--record-logits" in argv
     assert report.n_requests == chip_smoke.N_REQUESTS
     assert report.program_events_delta == 0
     for rec in report.records:
-        assert rec.logits.shape == (rec.n_new, report.engine.cfg.vocab)
+        assert rec.logits.shape == (rec.n_new, eng.cfg.vocab)
         # the recorded rows are the ones the greedy tokens came from
         np.testing.assert_array_equal(rec.logits.argmax(-1), rec.tokens)
 
@@ -83,6 +83,39 @@ def test_reference_mvm_matches_the_jnp_oracle():
     want = chip_smoke.reference_mvm(x, w, 1.0, 1.0, 1.0, 1.5, 8, 1024)
     lsb = 1.5 / 127
     assert np.mean(np.abs(np.asarray(y) - want) > 0.5 * lsb) < 1e-3
+
+
+@pytest.mark.parametrize("w_dtype", ["float32", "bfloat16"])
+def test_code_check_passes_f32_and_fails_a_bf16_mvm(w_dtype):
+    """A bf16 model's programmed layer, called as the model calls it, is
+    within TOL_CODES of the float64 reference only with an f32 MVM."""
+    import collections
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro.core import analog, engine
+
+    rng = np.random.default_rng(2)
+    k, n = 512, 256
+    layer = {
+        "w": jnp.asarray(rng.standard_normal((k, n)) * k**-0.5, jnp.float32),
+        "r_adc": jnp.float32(1.0),
+        "w_clip_buf": jnp.array([-1.0, 1.0], jnp.float32),
+        "out_scale_buf": jnp.float32(1.6),
+    }
+    Params = collections.namedtuple("Params", "layer gain_s")
+    acfg = dataclasses.replace(
+        analog.AnalogConfig().infer(b_adc=8), mode=engine.PCM_PROGRAMMED
+    )
+    share = chip_smoke.code_mismatch(
+        Params(layer, jnp.float32(1.0)), acfg, jnp.bfloat16,
+        w_dtype=w_dtype, rows=16,
+    )
+    if w_dtype == "float32":
+        assert share <= chip_smoke.TOL_CODES
+    else:
+        assert share > chip_smoke.TOL_CODES
 
 
 def test_compile_cache_dir_is_the_env_var_else_one_fixed_path(
