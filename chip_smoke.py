@@ -42,11 +42,11 @@ else in ``<repo>/.jax_cache``.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import os
 import sys
-import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
@@ -93,23 +93,20 @@ TOL_LOGITS = 0.1
 TOL_CODES = 1e-3
 
 
-class CompileClock:
-    """Seconds JAX spent in backend compiles, summed process-wide."""
+@contextlib.contextmanager
+def phase(name: str):
+    """Time a block as the recorder's span ``name`` (``repro.obs``). Yields
+    a dict that holds, after the block, its wall seconds (``wall``) and the
+    seconds JAX spent in backend compiles inside it (``compile``)."""
+    from repro import clock, obs
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def install(self) -> "CompileClock":
-        import jax
-
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-        return self
-
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if event == self.EVENT:
-            self.seconds += duration
+    out = {}
+    c0 = obs.counters().get("compile.s", 0.0)
+    with obs.span(name, clock.SYSTEM.now):
+        yield out
+    _, _, t0, t1, *_ = obs.last(name)
+    out["wall"] = t1 - t0
+    out["compile"] = obs.counters().get("compile.s", 0.0) - c0
 
 
 def _log(msg: str) -> None:
@@ -319,9 +316,8 @@ def serve_phase(model_args, extra=()):
 
     argv = [*model_args, *TRACE, *extra]
     _log("serve " + " ".join(argv))
-    t0 = time.perf_counter()
-    report, eng = serve.run(argv)
-    wall = time.perf_counter() - t0
+    with phase("smoke.serve") as served:
+        report, eng = serve.run(argv)
     if report.n_requests != N_REQUESTS:
         raise AssertionError(
             f"{report.n_requests} of {N_REQUESTS} requests retired"
@@ -335,7 +331,8 @@ def serve_phase(model_args, extra=()):
            for r in report.records):
         raise AssertionError("a request record lacks its logits")
     _log(f"served {report.n_requests} requests, {report.n_generated} "
-         f"tokens: serve.run wall={wall:.3f}s (run {report.wall:.3f}s)")
+         f"tokens: serve.run wall={served['wall']:.3f}s (run "
+         f"{report.wall:.3f}s)")
     return report, eng, argv
 
 
@@ -519,35 +516,34 @@ def main(argv=None) -> int:
     n_chips = 4 if args.four_chips else 1
     info = device_gate(n_chips)
     _log(f"compile cache: {compile_cache.enable()}")
-    clock = CompileClock().install()
 
     if args.four_chips:
-        t0 = time.perf_counter()
-        four_chip_phase(PUBLISHED, n_chips)
-        _log(f"four-chip phase: {time.perf_counter() - t0:.3f}s, "
-             f"backend compile {clock.seconds:.3f}s")
+        with phase("smoke.four_chip") as p:
+            four_chip_phase(PUBLISHED, n_chips)
+        _log(f"four-chip phase: {p['wall']:.3f}s, "
+             f"backend compile {p['compile']:.3f}s")
     else:
-        t0, c0 = time.perf_counter(), clock.seconds
-        report, eng, argv_b = serve_phase(PUBLISHED)
+        with phase("smoke.b") as p:
+            report, eng, argv_b = serve_phase(PUBLISHED)
         stats = jax.devices()[0].memory_stats() or {}
-        _log(f"phase b: {time.perf_counter() - t0:.3f}s, backend compile "
-             f"{clock.seconds - c0:.3f}s, peak_bytes_in_use="
+        _log(f"phase b: {p['wall']:.3f}s, backend compile "
+             f"{p['compile']:.3f}s, peak_bytes_in_use="
              f"{stats.get('peak_bytes_in_use')} of "
              f"{stats.get('bytes_limit')}")
         _log(report.summary())
 
-        t0, c0 = time.perf_counter(), clock.seconds
-        reference_phase(report, eng, argv_b)
-        _log(f"phase c: {time.perf_counter() - t0:.3f}s, backend compile "
-             f"{clock.seconds - c0:.3f}s")
+        with phase("smoke.c") as p:
+            reference_phase(report, eng, argv_b)
+        _log(f"phase c: {p['wall']:.3f}s, backend compile "
+             f"{p['compile']:.3f}s")
         base = served_logits(report)
         del report, eng
         gc.collect()
 
-        t0, c0 = time.perf_counter(), clock.seconds
-        kernel_phase(PUBLISHED, base)
-        _log(f"phase d: {time.perf_counter() - t0:.3f}s, backend compile "
-             f"{clock.seconds - c0:.3f}s")
+        with phase("smoke.d") as p:
+            kernel_phase(PUBLISHED, base)
+        _log(f"phase d: {p['wall']:.3f}s, backend compile "
+             f"{p['compile']:.3f}s")
         stats = jax.devices()[0].memory_stats() or {}
         _log(f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
 

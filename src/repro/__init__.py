@@ -17,3 +17,8 @@ import jax as _jax
 # must be the same chip no matter which mesh programmed it, so the whole
 # framework runs with the partitionable lowering (the default in newer JAX).
 _jax.config.update("jax_threefry_partitionable", True)
+
+# The recorder's process-wide hooks (compile events, garbage collections)
+# are installed when it is first imported: here, so every process that uses
+# the program has them.
+from repro import obs as _obs  # noqa: E402,F401

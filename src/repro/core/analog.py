@@ -264,6 +264,13 @@ def linear_apply(params: dict, x: Array, ctx: AnalogCtx) -> Array:
     return y
 
 
+def proj(params: dict, name: str, x: Array, ctx: AnalogCtx) -> Array:
+    """:func:`linear_apply` of the layer ``params[name]`` under the named
+    scope ``name``, so its device ops carry the projection's name."""
+    with jax.named_scope(name):
+        return linear_apply(params[name], x, ctx)
+
+
 def refresh_clip_ranges(params: dict, n_std: float = 2.0) -> dict:
     """Stage-1 helper: recompute every layer's static clip range from std(W).
 

@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import clock as clock_lib
+from repro import obs
 from repro.core import pcm as pcm_lib
 from repro.core import quant as quant_lib
 from repro.core.crossbar import LayerShape, Mapping, map_layers
@@ -221,9 +223,14 @@ def tile_matmul_quant(
     # for bf16 operands and on the CPU)
     hi = jax.lax.Precision.HIGHEST
     if not per_tile_adc or k <= tile_rows:
-        y = jnp.matmul(x, w, preferred_element_type=acc_dtype, precision=hi)
-        y = quant_lib.adc_quantize(y, r_adc, spec, qn_key)
-        return (y * out_scale).astype(x.dtype)
+        with jax.named_scope("crossbar"):
+            y = jnp.matmul(
+                x, w, preferred_element_type=acc_dtype, precision=hi
+            )
+        with jax.named_scope("adc"):
+            y = quant_lib.adc_quantize(y, r_adc, spec, qn_key)
+        with jax.named_scope("gdc"):
+            return (y * out_scale).astype(x.dtype)
 
     n_tiles = -(-k // tile_rows)
     pad = n_tiles * tile_rows - k
@@ -233,22 +240,26 @@ def tile_matmul_quant(
     xt = x.reshape(x.shape[:-1] + (n_tiles, tile_rows))
     wt = w.reshape(n_tiles, tile_rows, w.shape[-1])
     # (..., T, rows) x (T, rows, N) -> (..., T, N): one MVM per physical tile.
-    y_tiles = jnp.einsum(
-        "...tk,tkn->...tn", xt, wt, preferred_element_type=acc_dtype,
-        precision=hi,
-    )
-    y_tiles = quant_lib.adc_quantize(y_tiles, r_adc, spec, qn_key)
-    # per-tile quantized partials are grid values: store at compute dtype.
-    # Digital accumulation runs tile-serially (t=0..T-1), matching both the
-    # hardware's layer-serial ADC readout order and the fused kernel's VMEM
-    # accumulator -- float addition is non-associative, so a tree-reduce
-    # here would put the oracle one ulp off the kernel and break the
-    # kernel-vs-oracle bit-identity the low-bit parity tests pin down.
-    y_tiles = y_tiles.astype(x.dtype).astype(acc_dtype)
-    y = y_tiles[..., 0, :]
-    for t in range(1, n_tiles):
-        y = y + y_tiles[..., t, :]
-    return (y * out_scale).astype(x.dtype)
+    with jax.named_scope("crossbar"):
+        y_tiles = jnp.einsum(
+            "...tk,tkn->...tn", xt, wt, preferred_element_type=acc_dtype,
+            precision=hi,
+        )
+    with jax.named_scope("adc"):
+        y_tiles = quant_lib.adc_quantize(y_tiles, r_adc, spec, qn_key)
+        # per-tile quantized partials are grid values: store at compute
+        # dtype. Digital accumulation runs tile-serially (t=0..T-1),
+        # matching both the hardware's layer-serial ADC readout order and
+        # the fused kernel's VMEM accumulator -- float addition is
+        # non-associative, so a tree-reduce here would put the oracle one
+        # ulp off the kernel and break the kernel-vs-oracle bit-identity
+        # the low-bit parity tests pin down.
+        y_tiles = y_tiles.astype(x.dtype).astype(acc_dtype)
+        y = y_tiles[..., 0, :]
+        for t in range(1, n_tiles):
+            y = y + y_tiles[..., t, :]
+    with jax.named_scope("gdc"):
+        return (y * out_scale).astype(x.dtype)
 
 
 def execute_mvm(
@@ -270,16 +281,18 @@ def execute_mvm(
     if plan.use_kernel and qn_key is None:
         from repro.kernels import ops as kernel_ops
 
-        return kernel_ops.analog_mvm(
-            x_q,
-            w_eff,
-            r_adc=jnp.abs(r_adc),
-            out_scale=out_scale,
-            bits=plan.spec.b_adc,
-            tile_rows=plan.tile_rows,
-            per_tile_adc=plan.per_tile_adc,
-            interpret=plan.interpret,
-        )
+        # one kernel: the crossbar MVM with its ADC and GDC fused in
+        with jax.named_scope("crossbar"):
+            return kernel_ops.analog_mvm(
+                x_q,
+                w_eff,
+                r_adc=jnp.abs(r_adc),
+                out_scale=out_scale,
+                bits=plan.spec.b_adc,
+                tile_rows=plan.tile_rows,
+                per_tile_adc=plan.per_tile_adc,
+                interpret=plan.interpret,
+            )
     return tile_matmul_quant(
         x_q,
         w_eff,
@@ -310,7 +323,8 @@ def execute_programmed(
     programmed layers (``analog.analog_matmul``) and the fused decode grid
     all execute through here.
     """
-    x_q = quant_lib.dac_quantize(x, r_adc, gain_s, w_max, plan.spec, None)
+    with jax.named_scope("dac"):
+        x_q = quant_lib.dac_quantize(x, r_adc, gain_s, w_max, plan.spec, None)
     return execute_mvm(
         x_q.astype(w.dtype),
         w,
@@ -618,9 +632,10 @@ def program_weight(
     state = _jitted_program(cfg, len(stack), sharding)(
         keys, w, w_min_b, w_max_b
     )
-    w_eff, out_scale = drift_state(
-        state, t_seconds, cfg, n_stack_dims=len(stack), sharding=sharding
-    )
+    with obs.span("program.age", clock_lib.SYSTEM.now):
+        w_eff, out_scale = drift_state(
+            state, t_seconds, cfg, n_stack_dims=len(stack), sharding=sharding
+        )
     return w_eff, out_scale, state
 
 

@@ -25,6 +25,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import clock as clock_lib
+from repro import obs
 from repro.core.analog import AnalogConfig
 from repro.models.common import ModelConfig
 from repro.models.lm import lm_forward, lm_loss
@@ -60,25 +62,31 @@ def program_for_serving(
 
     ``t_seconds`` overrides the config's chip age for the first evaluation
     (drift-lifecycle serving compiles at the schedule's first age).
+
+    Recorded as the ``program`` span, which ends when the programmed params
+    are ready on the device.
     """
     from repro.core import engine
     from repro.launch import sharding as shd
 
-    shardings = None
-    if mesh is not None:
-        shardings = shd.program_shardings(params, mesh, model_cfg)
-        params = jax.device_put(params, shardings)
-    return engine.compile_program(
-        params,
-        analog_cfg,
-        key,
-        t_seconds=t_seconds,
-        transforms=transforms,
-        with_mapping=with_mapping,
-        shardings=shardings,
-        b_adc_overrides=b_adc_overrides,
-        chip_id=chip_id,
-    )
+    with obs.span("program", clock_lib.SYSTEM.now):
+        shardings = None
+        if mesh is not None:
+            shardings = shd.program_shardings(params, mesh, model_cfg)
+            params = jax.device_put(params, shardings)
+        program = engine.compile_program(
+            params,
+            analog_cfg,
+            key,
+            t_seconds=t_seconds,
+            transforms=transforms,
+            with_mapping=with_mapping,
+            shardings=shardings,
+            b_adc_overrides=b_adc_overrides,
+            chip_id=chip_id,
+        )
+        jax.block_until_ready(program.params)
+    return program
 
 
 def refresh_program(

@@ -153,16 +153,18 @@ def conv_apply(
         w2d = depthwise_densify(p["w"])
     else:
         w2d = conv_weight_as_matrix(p["w"])
-    patches = im2col(x, spec.kh, spec.kw, spec.stride, "SAME")
-    y = analog_matmul(
-        patches,
-        w2d.astype(x.dtype),
-        r_adc=p["r_adc"],
-        w_min=p["w_clip_buf"][0],
-        w_max=p["w_clip_buf"][1],
-        ctx=ctx,
-        out_scale=p.get("out_scale_buf"),
-    )
+    with jax.named_scope("im2col"):
+        patches = im2col(x, spec.kh, spec.kw, spec.stride, "SAME")
+    with jax.named_scope("mvm"):
+        y = analog_matmul(
+            patches,
+            w2d.astype(x.dtype),
+            r_adc=p["r_adc"],
+            w_min=p["w_clip_buf"][0],
+            w_max=p["w_clip_buf"][1],
+            ctx=ctx,
+            out_scale=p.get("out_scale_buf"),
+        )
     # BN folded to scale/bias; applied in the digital datapath (Sec. 5.2).
     y = y * p["bn_scale"].astype(y.dtype) + p["bn_bias"].astype(y.dtype)
     return jax.nn.relu(y) if relu else y
@@ -174,19 +176,22 @@ def cnn_apply(
     """x: (B, H, W, C) -> logits (B, n_classes)."""
     ctx = AnalogCtx(cfg=analog_cfg, gain_s=params["gain_s"], key=rng)
     for spec in cfg.convs:
-        x = conv_apply(params[spec.name], x, spec, ctx)
-    x = x.mean(axis=(1, 2))  # global average pool (digital)
+        with jax.named_scope(spec.name):
+            x = conv_apply(params[spec.name], x, spec, ctx)
+    with jax.named_scope("pool"):
+        x = x.mean(axis=(1, 2))  # global average pool (digital)
     fc = params["fc"]
-    y = analog_matmul(
-        x,
-        fc["w"].astype(x.dtype),
-        r_adc=fc["r_adc"],
-        w_min=fc["w_clip_buf"][0],
-        w_max=fc["w_clip_buf"][1],
-        ctx=ctx,
-        out_scale=fc.get("out_scale_buf"),
-    )
-    return y + fc["b"].astype(y.dtype)
+    with jax.named_scope("fc"):
+        y = analog_matmul(
+            x,
+            fc["w"].astype(x.dtype),
+            r_adc=fc["r_adc"],
+            w_min=fc["w_clip_buf"][0],
+            w_max=fc["w_clip_buf"][1],
+            ctx=ctx,
+            out_scale=fc.get("out_scale_buf"),
+        )
+        return y + fc["b"].astype(y.dtype)
 
 
 def cnn_loss(params, batch, analog_cfg, cfg, rng=None):

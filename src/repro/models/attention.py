@@ -20,7 +20,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.analog import AnalogCtx, linear_apply, linear_init
+from repro.core.analog import AnalogCtx, linear_init, proj
 from repro.models.common import ModelConfig, rope, shard
 
 Array = jax.Array
@@ -316,9 +316,9 @@ def attn_apply(
     """
     b, s, _ = x.shape
     hd, nh, nkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
-    q = _split_heads(linear_apply(params["wq"], x, ctx), nh, hd)
-    k = _split_heads(linear_apply(params["wk"], x, ctx), nkv, hd)
-    v = _split_heads(linear_apply(params["wv"], x, ctx), nkv, hd)
+    q = _split_heads(proj(params, "wq", x, ctx), nh, hd)
+    k = _split_heads(proj(params, "wk", x, ctx), nkv, hd)
+    v = _split_heads(proj(params, "wv", x, ctx), nkv, hd)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     q = shard(q, "batch", None, "heads", None)
@@ -351,9 +351,12 @@ def attn_apply(
         new_cache = PagedKVCache(
             ck, cv, cache.table, cache.length + 1, cache.cap_buf
         )
-        out = decode_attention(q, paged_view(new_cache))
+        with jax.named_scope("paged_view"):
+            view = paged_view(new_cache)
+        with jax.named_scope("scores"):
+            out = decode_attention(q, view)
         out = out.reshape(b, s, nh * hd)
-        return linear_apply(params["wo"], out, ctx), new_cache
+        return proj(params, "wo", out, ctx), new_cache
 
     new_cache = None
     s_cache = (
@@ -374,7 +377,8 @@ def attn_apply(
         new_len = cache.length.at[layer_idx].add(1)
         layer_cache = KVCache(ck[layer_idx], cv[layer_idx], ln + 1)
         new_cache = KVCache(ck, cv, new_len)
-        out = decode_attention(q, layer_cache, rolling=rolling)
+        with jax.named_scope("scores"):
+            out = decode_attention(q, layer_cache, rolling=rolling)
     elif cache is not None and s == 1:
         # decode: append to cache (circular slot for window buffers)
         idx = cache.length % s_cache if rolling else cache.length
@@ -389,7 +393,8 @@ def attn_apply(
             ck = jax.lax.dynamic_update_slice(cache.k, k, (0, idx, 0, 0))
             cv = jax.lax.dynamic_update_slice(cache.v, v, (0, idx, 0, 0))
         new_cache = KVCache(ck, cv, cache.length + 1)
-        out = decode_attention(q, new_cache, rolling=rolling)
+        with jax.named_scope("scores"):
+            out = decode_attention(q, new_cache, rolling=rolling)
     elif cache is not None:
         # prefill: write the prefix (for window buffers, only the last
         # ``s_cache`` keys, placed at their position-mod-window slots so
@@ -405,28 +410,30 @@ def attn_apply(
             ck = jax.lax.dynamic_update_slice(cache.k, k, (0, cache.length, 0, 0))
             cv = jax.lax.dynamic_update_slice(cache.v, v, (0, cache.length, 0, 0))
         new_cache = KVCache(ck, cv, cache.length + s)
-        out = chunked_attention(
-            q,
-            k,
-            v,
-            q_chunk=cfg.attn_chunk_q,
-            kv_chunk=cfg.attn_chunk_kv,
-            causal=True,
-            window=window,
-            q_offset=0,
-        )
+        with jax.named_scope("scores"):
+            out = chunked_attention(
+                q,
+                k,
+                v,
+                q_chunk=cfg.attn_chunk_q,
+                kv_chunk=cfg.attn_chunk_kv,
+                causal=True,
+                window=window,
+                q_offset=0,
+            )
     else:
-        out = chunked_attention(
-            q,
-            k,
-            v,
-            q_chunk=cfg.attn_chunk_q,
-            kv_chunk=cfg.attn_chunk_kv,
-            causal=True,
-            window=window,
-        )
+        with jax.named_scope("scores"):
+            out = chunked_attention(
+                q,
+                k,
+                v,
+                q_chunk=cfg.attn_chunk_q,
+                kv_chunk=cfg.attn_chunk_kv,
+                causal=True,
+                window=window,
+            )
     out = out.reshape(b, s, nh * hd)
-    return linear_apply(params["wo"], out, ctx), new_cache
+    return proj(params, "wo", out, ctx), new_cache
 
 
 def init_cache(

@@ -34,7 +34,9 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.analog import AnalogConfig, AnalogCtx, linear_apply, linear_init
+from repro.core.analog import (
+    AnalogConfig, AnalogCtx, linear_apply, linear_init, proj,
+)
 from repro.models import attention as attn_lib
 from repro.models import griffin as griffin_lib
 from repro.models import moe as moe_lib
@@ -78,11 +80,9 @@ def mlp_init(key: Array, cfg: ModelConfig) -> dict:
 
 
 def mlp_apply(params: dict, x: Array, ctx: AnalogCtx) -> Array:
-    h = jax.nn.silu(linear_apply(params["w1"], x, ctx)) * linear_apply(
-        params["w3"], x, ctx
-    )
+    h = jax.nn.silu(proj(params, "w1", x, ctx)) * proj(params, "w3", x, ctx)
     h = shard(h, "batch", None, "ffn")
-    return linear_apply(params["w2"], h, ctx)
+    return proj(params, "w2", h, ctx)
 
 
 def _block_init(key: Array, kind: str, cfg: ModelConfig) -> dict:
@@ -154,10 +154,11 @@ def _block_apply(
         new_cache = _writeback_cache(cache, nc, layer_idx)
     else:
         window = cfg.local_window if cfg.family == "hybrid" else None
-        out, new_cache = attn_lib.attn_apply(
-            params["attn"], h, ctx, cfg, positions=positions, cache=cache,
-            window=window, layer_idx=layer_idx,
-        )
+        with jax.named_scope("attn"):
+            out, new_cache = attn_lib.attn_apply(
+                params["attn"], h, ctx, cfg, positions=positions, cache=cache,
+                window=window, layer_idx=layer_idx,
+            )
     x = x + out
     h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
     if kind == "moe":
@@ -168,7 +169,8 @@ def _block_apply(
         else:
             x = x + moe_lib.moe_apply(params["moe"], h, ctx, cfg)
     else:
-        x = x + mlp_apply(params["ffn"], h, ctx)
+        with jax.named_scope("mlp"):
+            x = x + mlp_apply(params["ffn"], h, ctx)
     return x, new_cache
 
 
@@ -282,7 +284,8 @@ def lm_forward(
     """
     period = block_period(cfg)
     ctx0 = AnalogCtx(cfg=analog_cfg, gain_s=params.gain_s, key=rng)
-    h = _embed_inputs(params, batch, cfg, ctx0)
+    with jax.named_scope("embed"):
+        h = _embed_inputs(params, batch, cfg, ctx0)
     b, s, _ = h.shape
 
     if cache is not None:
@@ -392,14 +395,16 @@ def lm_forward(
         h, nc = _block_apply(tp, kind, h, ctx, cfg, positions, tc)
         new_tail_caches.append(nc)
 
-    h = rmsnorm_apply(params.final_norm, h, cfg.norm_eps)
-    if last_token_only:
-        if last_index is not None:
-            h = jnp.take_along_axis(h, last_index[:, None, None], axis=1)
-        else:
-            h = h[:, -1:, :]
-    logits = linear_apply(params.lm_head, h, ctx0)
-    logits = shard(logits, "batch", None, "vocab")
+    with jax.named_scope("head"):
+        h = rmsnorm_apply(params.final_norm, h, cfg.norm_eps)
+        if last_token_only:
+            if last_index is not None:
+                h = jnp.take_along_axis(h, last_index[:, None, None], axis=1)
+            else:
+                h = h[:, -1:, :]
+        with jax.named_scope("lm_head"):
+            logits = linear_apply(params.lm_head, h, ctx0)
+        logits = shard(logits, "batch", None, "vocab")
     if cfg.n_codebooks:
         logits = logits.reshape(*logits.shape[:-1], cfg.n_codebooks, cfg.vocab)
 

@@ -61,6 +61,7 @@ while it serves.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import warnings
 from collections import deque
 from typing import Any, Optional
@@ -70,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import clock as clock_lib
+from repro import obs
 from repro.core import engine as engine_mod
 from repro.core.analog import AnalogConfig
 from repro.core.engine import CiMProgram, DriftSchedule
@@ -93,6 +95,9 @@ from repro.serving.requests import Request, RequestRecord
 from repro.serving.scheduler import ContinuousScheduler
 
 Array = jax.Array
+
+#: ids of serving runs, on their spans in the recorder
+_run_ids = itertools.count()
 
 #: constructor keywords the pre-ServingConfig API accepted loosely; they
 #: now route through the deprecation shim into a ServingConfig
@@ -163,6 +168,8 @@ class ServeReport:
     n_slots: int
     n_steps: int  # decode steps
     slot_steps: int  # sum over steps of active slots
+    #: the run's ``engine.admit`` and ``engine.decode`` spans summed (those
+    #: the recorder still holds, ``obs.RING`` records back at most)
     t_prefill: float
     t_decode: float
     wall: float
@@ -756,8 +763,10 @@ class EngineRun:
         self.agree_sum = 0.0
         self.err_sum = 0.0
         self.decisions = 0
-        self.t_prefill = 0.0
-        self.t_decode = 0.0
+        #: the run's id on its ``engine.admit`` and ``engine.decode``
+        #: spans, and the recorder's cursor at its start
+        self.id = next(_run_ids)
+        self.obs0 = obs.cursor()
         self.events0 = engine_mod.program_event_count()
         self.allowed_events = 0
         self.reprograms0 = engine.reprograms
@@ -840,7 +849,8 @@ class EngineRun:
         """Admission phase: move arrived requests into free decode slots
         (scheduler-gated), prefilling each and seeding its slot."""
         eng = self.eng
-        now = self.now_fn() - self.t_start
+        t_admit = self.now_fn()
+        now = t_admit - self.t_start
         n_arrived = sum(1 for r in self.queue if r.arrival_t <= now)
         free = [i for i, s in enumerate(self.slots) if s is None]
         n_admit = self.scheduler.admit(
@@ -871,27 +881,41 @@ class EngineRun:
                     break
                 pending += need
             admitted.append((req, j))
+        if not admitted:
+            return
         for j in sorted((j for _, j in admitted), reverse=True):
             del self.queue[j]
 
-        if eng.paged:
-            self._admit_paged([r for r, _ in admitted], free)
-        else:
-            self._admit_rect([r for r, _ in admitted], free)
+        reqs = [r for r, _ in admitted]
+        with obs.span("engine.admit", self.now_fn, key=self.id,
+                      a=len(reqs), t0=t_admit):
+            for req in reqs:
+                obs.sample("engine.queue_wait", now - req.arrival_t, t_admit,
+                           req.rid)
+            if eng.paged:
+                self._admit_paged(reqs, free)
+            else:
+                self._admit_rect(reqs, free)
 
     def _admit_rect(self, reqs: list[Request], free: list[int]) -> None:
         eng = self.eng
         for req in reqs:
             slot = free.pop(0)
-            t0 = self.now_fn()
-            eng._prefill_shapes.add((1, int(req.prompt.size)))
-            tok0, logits0, pcache = eng._prefill(
-                eng.params,
-                eng._prefill_inputs(req),
-                jax.random.fold_in(eng.rng, 1_000_000 + req.rid),
-            )
-            self.cache = eng._write_main(self.cache, pcache, jnp.int32(slot))
-            self.cur = self.cur.at[slot, 0].set(tok0[0])
+            n_prompt = int(req.prompt.size)
+            eng._prefill_shapes.add((1, n_prompt))
+            with obs.span("engine.prefill", self.now_fn, a=n_prompt, b=1):
+                obs.count("engine.prefill_tokens_real", n_prompt)
+                obs.count("engine.prefill_tokens_computed", n_prompt)
+                tok0, logits0, pcache = eng._prefill(
+                    eng.params,
+                    eng._prefill_inputs(req),
+                    jax.random.fold_in(eng.rng, 1_000_000 + req.rid),
+                )
+            with obs.span("engine.slot_write", self.now_fn):
+                self.cache = eng._write_main(
+                    self.cache, pcache, jnp.int32(slot)
+                )
+                self.cur = self.cur.at[slot, 0].set(tok0[0])
             if eng._ref:
                 r_logits, r_pcache = eng._ref_prefill(
                     eng.ref_params, eng._prefill_inputs(req)
@@ -900,10 +924,11 @@ class EngineRun:
                     self.ref_cache, r_pcache, jnp.int32(slot)
                 )
                 self._count_decision(logits0, r_logits, 0)
-            self.t_prefill += self.now_fn() - t0
-            self.slots[slot] = _Slot(
+            with obs.span("engine.first_token", self.now_fn):
                 # repro-lint: disable=RL004 -- one sync per ADMISSION (not per decode tick): the first token must reach the host record
-                req, [int(tok0[0])], self.steps, self.now_fn() - self.t_start,
+                first = int(tok0[0])
+            self.slots[slot] = _Slot(
+                req, [first], self.steps, self.now_fn() - self.t_start,
                 logits=self._logits_rows(logits0, 0),
             )
             if self.on_token is not None:
@@ -941,16 +966,19 @@ class EngineRun:
             for j in range(len(chunk), pb):
                 toks[j] = toks[0]  # dummy rows repeat row 0
                 lens[j] = lens[0]
-            t0 = self.now_fn()
             eng._prefill_shapes.add((pb, sb))
-            tokv, logitsv, pcache = eng._prefill_bucket(
-                eng.params,
-                jnp.asarray(toks),
-                jnp.asarray(lens - 1),
-                jax.random.fold_in(
-                    eng.rng, 1_000_000 + chunk[0].rid
-                ),
-            )
+            with obs.span("engine.prefill", self.now_fn, a=sb, b=pb):
+                obs.count("engine.prefill_tokens_real",
+                          sum(int(r.prompt.size) for r in chunk))
+                obs.count("engine.prefill_tokens_computed", pb * sb)
+                tokv, logitsv, pcache = eng._prefill_bucket(
+                    eng.params,
+                    jnp.asarray(toks),
+                    jnp.asarray(lens - 1),
+                    jax.random.fold_in(
+                        eng.rng, 1_000_000 + chunk[0].rid
+                    ),
+                )
             for j, req in enumerate(chunk):
                 slot = free.pop(0)
                 n_prompt = int(req.prompt.size)
@@ -960,11 +988,12 @@ class EngineRun:
                 self.reserved += need - nbp_real
                 pvec = np.zeros((-(-sb // ps),), np.int32)
                 pvec[:nbp_real] = pages
-                self.cache = eng._write_slot_paged(
-                    self.cache, pcache, jnp.int32(slot), jnp.int32(j),
-                    jnp.asarray(pvec), jnp.int32(n_prompt),
-                )
-                self.cur = self.cur.at[slot, 0].set(tokv[j])
+                with obs.span("engine.slot_write", self.now_fn):
+                    self.cache = eng._write_slot_paged(
+                        self.cache, pcache, jnp.int32(slot), jnp.int32(j),
+                        jnp.asarray(pvec), jnp.int32(n_prompt),
+                    )
+                    self.cur = self.cur.at[slot, 0].set(tokv[j])
                 if eng._ref:
                     r_logits, r_pcache = eng._ref_prefill(
                         eng.ref_params, eng._prefill_inputs(req)
@@ -973,9 +1002,11 @@ class EngineRun:
                         self.ref_cache, r_pcache, jnp.int32(slot)
                     )
                     self._count_decision(logitsv[j : j + 1], r_logits, 0)
-                self.slots[slot] = _Slot(
+                with obs.span("engine.first_token", self.now_fn):
                     # repro-lint: disable=RL004 -- one sync per ADMISSION (bucketed prefill), amortized over the request's whole decode
-                    req, [int(tokv[j])], self.steps,
+                    first = int(tokv[j])
+                self.slots[slot] = _Slot(
+                    req, [first], self.steps,
                     self.now_fn() - self.t_start,
                     pages=pages, reserve_left=need - nbp_real,
                     logits=self._logits_rows(logitsv, j),
@@ -983,68 +1014,57 @@ class EngineRun:
                 if self.on_token is not None:
                     self.on_token(req.rid, self.slots[slot].tokens[0])
                 self.maybe_retire(slot)
-            self.t_prefill += self.now_fn() - t0
 
     def decode_step(self) -> None:
         """One jitted decode step over all live slots, plus retirement,
         the drift-policy tick, and the runaway guard."""
         eng = self.eng
-        if eng.paged:
-            # lazy growth: a slot whose next decode write crosses a
-            # page boundary gets one page off the free list (always
-            # available -- it was reserved at admission)
-            for i, st in enumerate(self.slots):
-                if st is None:
-                    continue
-                pos = int(st.req.prompt.size) + len(st.tokens) - 1
-                entry = pos // eng.page_size
-                if entry >= len(st.pages):
-                    (page,) = self.allocator.alloc(1)
-                    self.reserved -= 1
-                    st.reserve_left -= 1
-                    st.pages.append(page)
-                    self.cache = eng._append_page(
-                        self.cache, jnp.int32(i), jnp.int32(entry),
-                        jnp.int32(page),
+        now_fn = self.now_fn
+        with obs.span("engine.decode", now_fn, key=self.id):
+            if eng.paged:
+                with obs.span("engine.page_append", now_fn):
+                    self._append_pages()
+            with obs.span("engine.decode_launch", now_fn):
+                nxt, logits, self.cache = eng._decode(
+                    eng.params, self.cur, self.cache,
+                    jax.random.fold_in(eng.rng, self.steps),
+                )
+                if eng._ref:
+                    r_logits, self.ref_cache = eng._ref_decode(
+                        eng.ref_params, self.cur, self.ref_cache
                     )
+                    a_v, e_v = eng._count(logits, r_logits)
+            with obs.span("engine.decode_sync", now_fn):
+                if eng._ref:
+                    a_np, e_np = np.asarray(a_v), np.asarray(e_v)
+                nxt_np = np.asarray(nxt)
+            with obs.span("engine.tokens", now_fn):
+                self.steps += 1
+                active = [i for i, s in enumerate(self.slots) if s is not None]
+                self.slot_steps += len(active)
+                rows = (
+                    np.asarray(logits, np.float32)
+                    if eng.config.record_logits else None
+                )
+                for i in active:
+                    self.slots[i].tokens.append(int(nxt_np[i]))
+                    if rows is not None:
+                        self.slots[i].logits.append(rows[i])
+                    if self.on_token is not None:
+                        self.on_token(
+                            self.slots[i].req.rid, self.slots[i].tokens[-1]
+                        )
+                    if eng._ref:
+                        self.agree_sum += float(a_np[i])
+                        self.err_sum += float(e_np[i])
+                        self.decisions += 1
+                        self.seg_agree += float(a_np[i])
+                        self.seg_dec += 1
+                self.cur = nxt[:, None]
+                for i in active:
+                    self.maybe_retire(i)
 
-        t0 = self.now_fn()
-        nxt, logits, self.cache = eng._decode(
-            eng.params, self.cur, self.cache,
-            jax.random.fold_in(eng.rng, self.steps),
-        )
-        if eng._ref:
-            r_logits, self.ref_cache = eng._ref_decode(
-                eng.ref_params, self.cur, self.ref_cache
-            )
-            a_v, e_v = eng._count(logits, r_logits)
-            a_np, e_np = np.asarray(a_v), np.asarray(e_v)
-        nxt_np = np.asarray(nxt)
-        self.t_decode += self.now_fn() - t0
-        self.steps += 1
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        self.slot_steps += len(active)
-        rows = (
-            np.asarray(logits, np.float32)
-            if eng.config.record_logits else None
-        )
-        for i in active:
-            self.slots[i].tokens.append(int(nxt_np[i]))
-            if rows is not None:
-                self.slots[i].logits.append(rows[i])
-            if self.on_token is not None:
-                self.on_token(self.slots[i].req.rid, self.slots[i].tokens[-1])
-            if eng._ref:
-                self.agree_sum += float(a_np[i])
-                self.err_sum += float(e_np[i])
-                self.decisions += 1
-                self.seg_agree += float(a_np[i])
-                self.seg_dec += 1
-        self.cur = nxt[:, None]
-        for i in active:
-            self.maybe_retire(i)
-
-        self._drift_tick()
+                self._drift_tick()
 
         if self.max_steps is not None and self.steps >= self.max_steps:
             raise RuntimeError(
@@ -1052,6 +1072,26 @@ class EngineRun:
                 f"{self.n_active} live slots and "
                 f"{len(self.queue)} queued requests"
             )
+
+    def _append_pages(self) -> None:
+        """Lazy growth: a slot whose next decode write crosses a page
+        boundary gets one page off the free list (always available -- it
+        was reserved at admission)."""
+        eng = self.eng
+        for i, st in enumerate(self.slots):
+            if st is None:
+                continue
+            pos = int(st.req.prompt.size) + len(st.tokens) - 1
+            entry = pos // eng.page_size
+            if entry >= len(st.pages):
+                (page,) = self.allocator.alloc(1)
+                self.reserved -= 1
+                st.reserve_left -= 1
+                st.pages.append(page)
+                self.cache = eng._append_page(
+                    self.cache, jnp.int32(i), jnp.int32(entry),
+                    jnp.int32(page),
+                )
 
     def _logits_rows(self, logits, row: int) -> Optional[list]:
         """A new slot's recorded logits: its first token's row, if kept."""
@@ -1202,6 +1242,10 @@ class EngineRun:
                 f"allocated and {self.reserved} still reserved after every "
                 "request retired -- admit/retire must conserve the free list"
             )
+        spent = {"engine.admit": 0.0, "engine.decode": 0.0}
+        for kind, name, t0, t1, _, _, key, _, _ in obs.records(self.obs0):
+            if kind == obs.SPAN and key == self.id and name in spent:
+                spent[name] += t1 - t0
         counters = None
         if eng._ref:
             counters = {
@@ -1219,8 +1263,8 @@ class EngineRun:
             n_slots=eng.n_slots,
             n_steps=self.steps,
             slot_steps=self.slot_steps,
-            t_prefill=self.t_prefill,
-            t_decode=self.t_decode,
+            t_prefill=spent["engine.admit"],
+            t_decode=spent["engine.decode"],
             wall=wall,
             counters=counters,
             age_events=self.age_events,

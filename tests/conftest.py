@@ -16,21 +16,11 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 # --------------------------------------------------------------- retraces
 #
-# One listener, registered once per process (jax.monitoring has no
-# unregister), counting XLA compilations: the backend_compile event fires
-# exactly once per new trace/compile and never on a jit cache hit. It times
-# the persistent-cache lookup too, so a program read back from that cache
-# counts as well (tests/test_retrace_fixture.py).
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_count = [0]
-
-
-def _count_compiles(key: str, _duration: float, **_kw) -> None:
-    if key == _COMPILE_EVENT:
-        _compile_count[0] += 1
-
-
-jax.monitoring.register_event_duration_secs_listener(_count_compiles)
+# The program's recorder (repro.obs) counts XLA compilations: the
+# backend_compile event fires exactly once per new trace/compile and never on
+# a jit cache hit. It times the persistent-cache lookup too, so a program
+# read back from that cache counts as well (tests/test_retrace_fixture.py).
+from repro import obs  # noqa: E402
 
 
 @pytest.fixture
@@ -51,9 +41,9 @@ def assert_max_retraces():
 
     @contextlib.contextmanager
     def _bound(n_max: int):
-        before = _compile_count[0]
+        before = obs.counters().get("compile.n", 0)
         yield
-        n_new = _compile_count[0] - before
+        n_new = obs.counters().get("compile.n", 0) - before
         assert n_new <= n_max, (
             f"{n_new} new jit compilation(s) in a block that allows "
             f"{n_max} -- a retrace crept into a warmed path (loop-varying "
