@@ -3,7 +3,9 @@
 Three execution paths, selected by input shape/cache:
   * training / short prefill  -- chunked online-softmax ("flash"-style) scan,
     O(chunk^2) live memory instead of O(S^2): mandatory at 32k context;
-  * decode                    -- one query token against a KV cache;
+  * decode                    -- one query token against a KV cache (over a
+    paged cache on a TPU: the Pallas paged-attention kernel, see
+    PagedKVCache);
   * local (sliding-window)    -- banded variant used by recurrentgemma.
 
 Per the paper's hardware model, Q/K/V/O *projections* are stationary-weight
@@ -15,10 +17,13 @@ matmuls; the distinction matters for the AON-CiM energy model only.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
+from jax.interpreters import mlir
 
 from repro.core.analog import AnalogCtx, linear_init, proj
 from repro.models.common import ModelConfig, rope, shard
@@ -225,11 +230,27 @@ class PagedKVCache(NamedTuple):
     usage, not provisioning. Page id 0 is a reserved *scratch* page: it is
     never allocated, unused page-table entries point at it, and retired
     slots (whose pages have been returned to the free list) write their
-    dead decode tokens into it instead of corrupting reassigned pages.
+    dead decode tokens into it instead of corrupting reassigned pages. A
+    slot whose table row starts with page 0 holds no request: every live
+    slot's first page is real.
+
+    The pools are head-major, ``(n_kv, n_pages, page_size, hd)``: one kv
+    head's page is one contiguous ``(page_size, hd)`` block, the unit the
+    TPU's paged-attention kernel copies. Decode attention over the cache
+    (:func:`paged_decode_attention`) takes one of two paths, chosen by the
+    backend the program is compiled for and the cache's sharding:
+
+    * on a TPU with the cache on one device, the Pallas kernel reads each
+      live slot's pages straight from the pool, in bf16, up to the slot's
+      length, and skips idle slots;
+    * elsewhere (the CPU, a cache sharded over several devices) the pool is
+      gathered into the rectangle a slot cache would hold
+      (:func:`paged_view`) and attended by :func:`decode_attention`. Only
+      this path is bitwise identical to the rectangular slot cache.
     """
 
-    k: Array  # (n_pages, page_size, n_kv, hd) -- pool shared by all slots
-    v: Array  # (n_pages, page_size, n_kv, hd)
+    k: Array  # (n_kv, n_pages, page_size, hd) -- pool shared by all slots
+    v: Array  # (n_kv, n_pages, page_size, hd)
     table: Array  # (B, pages_per_slot) int32 page ids; 0 = scratch page
     length: Array  # (B,) int32 tokens written per slot
     #: zero-element (s_max, 0) buffer: shape-encodes the slot's virtual
@@ -240,7 +261,7 @@ class PagedKVCache(NamedTuple):
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[2]
 
     @property
     def s_max(self) -> int:
@@ -270,7 +291,7 @@ def init_paged_cache(
     # NOTE: n_pages may be much smaller than batch * pages_per_slot (that
     # is the point: s_max is VIRTUAL capacity); the serving engine's
     # admission reservations keep actual usage within the pool.
-    shape = (n_pages, page_size, cfg.n_kv_heads, cfg.hd)
+    shape = (cfg.n_kv_heads, n_pages, page_size, cfg.hd)
     return PagedKVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
@@ -281,20 +302,135 @@ def init_paged_cache(
 
 
 def paged_view(cache: PagedKVCache) -> KVCache:
-    """Gather the pool through the page tables into a rectangular
-    (B, s_max) slot-cache view.
+    """Gather the head-major pool through the page tables into a
+    rectangular (B, s_max, n_kv, hd) slot-cache view.
 
-    Pure data movement (gather + reshape + slice, no arithmetic), sliced to
-    the shape-encoded virtual capacity: attention over the view is bitwise
-    identical to attention over a rectangular slot cache holding the same
-    tokens. Positions past a slot's length read scratch/garbage rows and
-    are masked to exact-zero probability by :func:`decode_attention`.
+    Pure data movement (gather + transpose + reshape + slice, no
+    arithmetic), sliced to the shape-encoded virtual capacity: attention
+    over the view is bitwise identical to attention over a rectangular slot
+    cache holding the same tokens. Positions past a slot's length read
+    scratch/garbage rows and are masked to exact-zero probability by
+    :func:`decode_attention`. The decode path off the TPU (see
+    :class:`PagedKVCache`).
     """
     b, pages_per_slot = cache.table.shape
+    n_kv, _, ps, hd = cache.k.shape
+
+    def gather(pool):
+        rows = pool[:, cache.table]  # (n_kv, B, pages_per_slot, ps, hd)
+        rows = rows.transpose(1, 2, 3, 0, 4)
+        return rows.reshape(b, pages_per_slot * ps, n_kv, hd)[:, : cache.s_max]
+
+    return KVCache(gather(cache.k), gather(cache.v), cache.length)
+
+
+#: tokens of one slot's pages the paged-attention kernel reads per step of
+#: its loop (its compute block), as near as a divisor of the slot's pages
+#: allows: 256 read the pages fastest of 64, 128 and 256 on a TPU v5e
+#: (PERF.md, section 6)
+KERNEL_BLOCK_TOKENS = 256
+
+
+def kernel_block_pages(page_size: int, pages_per_slot: int) -> int:
+    """Pages per compute block of the paged-attention kernel: the largest
+    divisor of ``pages_per_slot`` that holds at most
+    :data:`KERNEL_BLOCK_TOKENS` tokens (one page at the least)."""
+    most = max(1, min(KERNEL_BLOCK_TOKENS // page_size, pages_per_slot))
+    return max(d for d in range(1, most + 1) if pages_per_slot % d == 0)
+
+
+def paged_attention_kernel(q: Array, cache: PagedKVCache) -> Array:
+    """Decode attention over a paged cache through the TPU's Pallas
+    paged-attention kernel. q: (B, 1, H, D) -> (B, 1, H, D).
+
+    A live slot's pages are read from the pool in bf16, a compute block at
+    a time, up to its length; an idle slot (table row starting with the
+    scratch page) is given length 0, which the kernel skips, and its row is
+    exact zeros. Table entries past a live slot's pages point at its first
+    page, so a live slot never reads the scratch page the idle slots write
+    into. q is scaled by ``D**-0.5`` in f32, as :func:`decode_attention`
+    scales the scores.
+    """
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention,
+    )
+
+    d = q.shape[-1]
+    pages_per_slot = cache.table.shape[1]
     ps = cache.page_size
-    k = cache.k[cache.table].reshape(b, pages_per_slot * ps, *cache.k.shape[2:])
-    v = cache.v[cache.table].reshape(b, pages_per_slot * ps, *cache.v.shape[2:])
-    return KVCache(k[:, : cache.s_max], v[:, : cache.s_max], cache.length)
+    live = cache.table[:, 0] != 0
+    n = jnp.where(live, jnp.minimum(cache.length, cache.s_max), 0)
+    used = jnp.arange(pages_per_slot)[None, :] < -(-n // ps)[:, None]
+    table = jnp.where(used, cache.table, cache.table[:, :1])
+    out = paged_attention(
+        q[:, 0].astype(jnp.float32) * d**-0.5, cache.k, cache.v, n, table,
+        pages_per_compute_block=kernel_block_pages(ps, pages_per_slot),
+    )
+    out = jnp.where(live[:, None, None], out, 0.0)
+    return out[:, None].astype(q.dtype)
+
+
+def _lowering(path):
+    """``path(q, cache)`` as a lowering of :data:`_paged_decode_p`."""
+
+    def attend(q, k, v, table, length, *, s_max):
+        cap = jnp.zeros((s_max, 0), jnp.int32)
+        return path(q, PagedKVCache(k, v, table, length, cap))
+
+    return mlir.lower_fun(attend, multiple_results=False)
+
+
+def _view_path(q: Array, cache: PagedKVCache) -> Array:
+    with jax.named_scope("paged_view"):
+        view = paged_view(cache)
+    with jax.named_scope("scores"):
+        return decode_attention(q, view)
+
+
+def _kernel_path(q: Array, cache: PagedKVCache) -> Array:
+    with jax.named_scope("scores"):
+        return paged_attention_kernel(q, cache)
+
+
+#: paged decode attention, lowered per platform: the kernel for a TPU, the
+#: gathered view for every other backend (the choice is the compiler's
+#: target, so a program compiled for a described TPU takes the kernel too)
+_paged_decode_p = jex_core.Primitive("paged_decode_attention")
+_paged_decode_p.def_abstract_eval(lambda q, *_, **__: q)
+_paged_decode_p.def_impl(
+    lambda *args, **params: jax.jit(
+        functools.partial(_paged_decode_p.bind, **params)
+    )(*args)
+)
+mlir.register_lowering(_paged_decode_p, _lowering(_view_path))
+mlir.register_lowering(_paged_decode_p, _lowering(_kernel_path), platform="tpu")
+
+
+def _on_one_device(x) -> bool:
+    return jax.typeof(x).sharding.mesh.size <= 1
+
+
+def paged_decode_attention(q: Array, cache: PagedKVCache) -> Array:
+    """One-token attention over a paged cache (after this step's token is
+    written). q: (B, 1, H, D). The kernel on a TPU with the cache on one
+    device, else the gathered view (see :class:`PagedKVCache`): the kernel
+    lowers only through Mosaic and is not partitioned across devices."""
+    if not (
+        jax.sharding.get_abstract_mesh().size <= 1
+        and all(_on_one_device(x) for x in (q, cache.k, cache.v))
+    ):
+        return _view_path(q, cache)
+    return _paged_decode_p.bind(
+        q, cache.k, cache.v, cache.table, cache.length, s_max=cache.s_max
+    )
+
+
+def kernel_engages(cache: PagedKVCache) -> bool:
+    """Whether decode over this (concrete) cache takes the kernel: its pool
+    lies on one TPU device. The host-side twin of the choice
+    :func:`paged_decode_attention` makes while tracing."""
+    devices = cache.k.sharding.device_set
+    return len(devices) == 1 and next(iter(devices)).platform == "tpu"
 
 
 def attn_apply(
@@ -338,23 +474,26 @@ def attn_apply(
                 "paging applies to global-attention caches only"
             )
         # decode: write this token's K/V row at (page, offset) of each
-        # slot's current position, then attend over the gathered view --
-        # the same values a rectangular slot cache would hold, so the
-        # attention math is bitwise identical (see paged_view).
+        # slot's current position, then attend over the slots' pages
         ps = cache.page_size
         page = jnp.take_along_axis(
             cache.table, (cache.length // ps)[:, None], axis=1, mode="clip"
         )[:, 0]  # (B,) -- OOB entries of retired slots clip to scratch
-        off = cache.length % ps
-        ck = cache.k.at[page, off].set(k[:, 0].astype(cache.k.dtype))
-        cv = cache.v.at[page, off].set(v[:, 0].astype(cache.v.dtype))
+        at_off = jnp.arange(ps)[None, :] == (cache.length % ps)[:, None]
+
+        def put(pool, x):
+            # whole pages in and out: a scatter of single rows would make
+            # the TPU compiler lay the pool out page-major and copy all of
+            # it to and from the kernel's head-major layout every step
+            rows = x[:, 0].swapaxes(0, 1)[:, :, None].astype(pool.dtype)
+            pages = jnp.where(at_off[None, :, :, None], rows, pool[:, page])
+            return pool.at[:, page].set(pages)
+
         new_cache = PagedKVCache(
-            ck, cv, cache.table, cache.length + 1, cache.cap_buf
+            put(cache.k, k), put(cache.v, v), cache.table, cache.length + 1,
+            cache.cap_buf,
         )
-        with jax.named_scope("paged_view"):
-            view = paged_view(new_cache)
-        with jax.named_scope("scores"):
-            out = decode_attention(q, view)
+        out = paged_decode_attention(q, new_cache)
         out = out.reshape(b, s, nh * hd)
         return proj(params, "wo", out, ctx), new_cache
 

@@ -609,9 +609,10 @@ def reset_cache_slot(cache: tuple, slot) -> tuple:
 # The engine owns ONE paged decode cache (init_lm_cache with stacked=False,
 # paged=True): per attention layer a page pool + per-slot page tables, one
 # shared page-id space (the allocator hands out ids valid in every layer).
-# Admission scatters a request's rectangular prefill cache into its pages;
-# growth appends a page id to the slot's table; retirement zeroes the
-# slot's pages/table/length so the ids can be reissued.
+# Admission scatters a request's rectangular prefill cache into its pages
+# (the pools are head-major, (n_kv, n_pages, page_size, hd)); growth
+# appends a page id to the slot's table; retirement zeroes the slot's
+# pages/table/length so the ids can be reissued.
 # ---------------------------------------------------------------------------
 
 _is_paged = lambda x: isinstance(x, attn_lib.PagedKVCache)
@@ -645,7 +646,8 @@ def write_cache_slot_paged(
             pad = nbp * ps - rows.shape[0]
             if pad:
                 rows = jnp.pad(rows, ((0, pad), (0, 0), (0, 0)))
-            return pool.at[pages].set(rows.reshape(nbp, ps, *rows.shape[1:]))
+            rows = rows.reshape(nbp, ps, *rows.shape[1:])
+            return pool.at[:, pages].set(rows.transpose(2, 0, 1, 3))
 
         table_row = (
             jnp.zeros((dst.table.shape[1],), jnp.int32).at[:nbp].set(pages)
@@ -686,10 +688,11 @@ def free_cache_slot_paged(cache: tuple, slot, pages) -> tuple:
     pages = jnp.asarray(pages, jnp.int32)
 
     def free(dst: attn_lib.PagedKVCache):
-        z = jnp.zeros((pages.shape[0],) + dst.k.shape[1:], dst.k.dtype)
+        n_kv, _, ps, hd = dst.k.shape
+        z = jnp.zeros((n_kv, pages.shape[0], ps, hd), dst.k.dtype)
         return dst._replace(
-            k=dst.k.at[pages].set(z),
-            v=dst.v.at[pages].set(z),
+            k=dst.k.at[:, pages].set(z),
+            v=dst.v.at[:, pages].set(z),
             table=dst.table.at[slot].set(0),
             length=dst.length.at[slot].set(0),
         )

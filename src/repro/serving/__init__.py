@@ -72,10 +72,13 @@ Continuous batching here is *semantically inert*: slots are independent
 cache position), so per-request generations are bit-identical to serving
 the request alone on a fresh engine -- only throughput changes. The
 ``benchmarks/serving_bench.py`` rows quantify it. Paged serving preserves
-the same invariant: the paged decode view gathers exactly the rectangle a
-slot cache would hold, and right-padded prefill is bitwise inert because
-the chunked-attention kv reduction is shape-stable -- so generations stay
-bit-identical to the rectangular engine on the same frozen chip draw.
+the same invariant off the TPU: the paged decode view gathers exactly the
+rectangle a slot cache would hold, and right-padded prefill is bitwise
+inert because the chunked-attention kv reduction is shape-stable -- so
+generations stay bit-identical to the rectangular engine on the same
+frozen chip draw. On a TPU the paged decode step reads the pages through
+the Pallas paged-attention kernel instead (bf16 rounding apart from the
+view, see ``models.attention.PagedKVCache``).
 One exception: MoE capacity routing pools tokens across the decode batch
 (keep/drop competes for expert capacity), so for the moe family
 co-scheduled requests can route differently than solo ones -- serve.py

@@ -730,6 +730,18 @@ class EngineRun:
             # pool exhaustion cannot deadlock the decode loop.
             self.allocator = PageAllocator(engine.n_pages)
             self.reserved = 0
+            # KV tokens the paged kernel reads per slot and compute block
+            # (None: the decode step attends over the gathered view)
+            leaf = jax.tree.leaves(
+                self.cache, is_leaf=lambda x: isinstance(x, attn_lib.PagedKVCache)
+            )[0]
+            self.kv_block = (
+                attn_lib.kernel_block_pages(
+                    engine.page_size, engine.pages_per_slot
+                ) * engine.page_size
+                if engine.mesh is None and attn_lib.kernel_engages(leaf)
+                else None
+            )
         elif engine.fused:
             # one stacked (L, B, S, kv, hd) buffer: the fused grid's layer
             # axis doubles as its BlockSpec index
@@ -1024,6 +1036,7 @@ class EngineRun:
             if eng.paged:
                 with obs.span("engine.page_append", now_fn):
                     self._append_pages()
+                self._count_kv_reads()
             with obs.span("engine.decode_launch", now_fn):
                 nxt, logits, self.cache = eng._decode(
                     eng.params, self.cur, self.cache,
@@ -1092,6 +1105,21 @@ class EngineRun:
                     self.cache, jnp.int32(i), jnp.int32(entry),
                     jnp.int32(page),
                 )
+
+    def _count_kv_reads(self) -> None:
+        """Count the KV tokens this step's paged attention reads, from the
+        slots' bookkeeping: on the kernel path each live slot's length
+        rounded up to the compute block, else the whole gathered view
+        (``engine.kv_tokens_view``, n_slots x s_max)."""
+        eng = self.eng
+        view = eng.n_slots * eng.s_max
+        bk = self.kv_block
+        read = view if bk is None else sum(
+            -(-min(st.req.prompt.size + len(st.tokens), eng.s_max) // bk) * bk
+            for st in self.slots if st is not None
+        )
+        obs.count("engine.kv_tokens_read", read)
+        obs.count("engine.kv_tokens_view", view)
 
     def _logits_rows(self, logits, row: int) -> Optional[list]:
         """A new slot's recorded logits: its first token's row, if kept."""

@@ -13,6 +13,14 @@ attention layer's pool):
 * every allocated page is eventually freed exactly once -- the free list
   is conserved across admit/retire storms.
 
+Each layer's pools are head-major, ``(n_kv, n_pages, page_size, hd)``.
+On a TPU with the cache on one device, decode attention reads each live
+slot's pages straight from the pool through the Pallas paged-attention
+kernel (bf16, only up to the slot's length; idle slots are skipped).
+Elsewhere -- the CPU, a cache sharded over several devices -- it gathers
+the pool into the rectangle a slot cache would hold; only that path is
+bitwise identical to the rectangular engine (``models.attention``).
+
 :class:`PageAllocator` is deliberately a plain-Python free list (ids are
 engine-side bookkeeping; only the page *tables* live on device), which
 keeps the invariants directly property-testable (tests/test_properties.py).

@@ -177,6 +177,43 @@ def test_prefill_token_counters_count_rows_times_bucket(cfg, program):
     assert all(r[5] in ids and r[2] == ids[r[5]][2] for r in counts)
 
 
+def _kv_counts(recs, name):
+    return [r for r in recs if r[0] == obs.COUNT and r[1] == name]
+
+
+def test_kv_read_counters_count_the_view_off_the_tpu(paged_run):
+    """On the CPU the decode step attends over the whole gathered view:
+    each step reads n_slots x s_max tokens, counted in its decode span."""
+    recs, rep, _ = paged_run
+    decodes = {r[4]: r for r in _spans(recs) if r[1] == "engine.decode"}
+    for name in ("engine.kv_tokens_read", "engine.kv_tokens_view"):
+        counts = _kv_counts(recs, name)
+        assert len(counts) == rep.n_steps
+        assert all(r[7] == 4 * 48 for r in counts)
+        assert all(r[5] in decodes and r[2] == decodes[r[5]][2]
+                   for r in counts)
+
+
+def test_kv_read_counter_rounds_live_lengths_to_the_kernel_block(
+    cfg, program
+):
+    """On the kernel path a step reads each live slot's length rounded up
+    to the compute block, from the host's bookkeeping."""
+    eng = _engine(cfg, program)
+    rng = np.random.default_rng(2)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab, n),
+                    max_new_tokens=4) for i, n in enumerate((5, 17))]
+    run = eng.start_run(scheduler=BucketedScheduler(), clock=VirtualClock())
+    assert run.kv_block is None  # the CPU takes the gathered view
+    run.kv_block = 16  # as a TPU's kernel would read
+    run.submit(reqs)
+    run.admit_arrived()
+    c0 = obs.cursor()
+    run.decode_step()  # lengths 6 and 18: one block and two
+    (read,) = _kv_counts(obs.records(c0), "engine.kv_tokens_read")
+    assert read[7] == 16 + 32
+
+
 def test_report_timings_are_the_runs_spans(paged_run):
     recs, rep, _ = paged_run
     spans = _spans(recs)

@@ -618,10 +618,10 @@ def test_paged_free_leaves_other_slots_pages_untouched(
     pvec[:2] = (1, 2)
     freed = free_cache_slot_paged(paged, 0, pvec)
     for (k0, v0, tab0, len0), c in zip(before, paged_leaves(freed)):
-        assert not np.any(np.asarray(c.k)[1:3])  # slot 0's pages zeroed
-        assert not np.any(np.asarray(c.v)[1:3])
-        np.testing.assert_array_equal(np.asarray(c.k)[3:5], k0[3:5])
-        np.testing.assert_array_equal(np.asarray(c.v)[3:5], v0[3:5])
+        assert not np.any(np.asarray(c.k)[:, 1:3])  # slot 0's pages zeroed
+        assert not np.any(np.asarray(c.v)[:, 1:3])
+        np.testing.assert_array_equal(np.asarray(c.k)[:, 3:5], k0[:, 3:5])
+        np.testing.assert_array_equal(np.asarray(c.v)[:, 3:5], v0[:, 3:5])
         np.testing.assert_array_equal(np.asarray(c.table)[1], tab0[1])
         assert int(np.asarray(c.length)[1]) == int(len0[1]) == 8
         assert not np.any(np.asarray(c.table)[0])

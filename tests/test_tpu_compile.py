@@ -12,6 +12,7 @@ be read back).
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -102,3 +103,44 @@ def test_fused_grid_refused_by_mosaic(one_chip):
     )
     with pytest.raises(Exception, match="Up to 1 batch dim supported"):
         step.lower(place(params), tok, place(cache)).compile()
+
+
+def test_paged_decode_attention_is_the_kernel_for_v5e(one_chip):
+    """The paged branch of ``attn_apply`` at the conv cell's shapes (64
+    slots of 2048 tokens, 8192 pages of 16, 16 x 128 heads, bf16) compiles
+    to the Pallas paged-attention kernel, and no f32 copy of the gathered
+    (64, 2048) view remains: that copy was most of the decode step. With
+    the cache donated, as the engine donates it, the token write leaves
+    the pools in place: no copy of a whole pool to another layout."""
+    import re
+
+    from repro.core.analog import AnalogCtx
+    from repro.models import attention as attn_lib
+
+    cfg = configs.get("olmo-1b")
+    n_slots, s_max, bf16 = 64, 2048, jnp.bfloat16
+    params = jax.eval_shape(
+        lambda k: attn_lib.attn_init(k, cfg), jax.random.PRNGKey(0)
+    )
+    cache = jax.eval_shape(lambda: attn_lib.init_paged_cache(
+        cfg, n_slots, s_max, bf16, page_size=16, n_pages=8192))
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t
+    )
+    x = jax.ShapeDtypeStruct((n_slots, 1, cfg.d_model), bf16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32, sharding=one_chip)
+
+    def step(p, x, pos, c):
+        ctx = AnalogCtx(cfg=AnalogConfig(), gain_s=jnp.ones((), jnp.float32))
+        return attn_lib.attn_apply(p, x, ctx, cfg, positions=pos, cache=c)
+
+    text = jax.jit(step, donate_argnums=(3,)).lower(
+        place(params), x, pos, place(cache)).compile().as_text()
+    assert "tpu_custom_call" in text
+    view = n_slots * s_max * cfg.n_kv_heads * cfg.hd
+    f32 = {tuple(map(int, d.split(","))) for d in
+           re.findall(r"f32\[(\d+(?:,\d+)*)\]", text)}
+    assert not [d for d in f32 if np.prod(d) == view], sorted(f32)
+    pool = "bf16[{}]".format(",".join(map(str, cache.k.shape)))
+    copies = [ln for ln in text.splitlines() if f"= {pool}" in ln and " copy(" in ln]
+    assert not copies, copies
