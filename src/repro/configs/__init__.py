@@ -1,4 +1,4 @@
-"""Config registry: the 10 assigned architectures + the paper's TinyML models.
+"""Config registry: the assigned LM architectures + the paper's TinyML models.
 
 ``get(arch_id)`` returns the full-size ModelConfig; ``get_smoke(arch_id)``
 returns the reduced same-family config used by CPU smoke tests. Shape cells
@@ -23,6 +23,7 @@ LM_ARCHS = {
     "llama4-maverick-400b-a17b": "llama4_maverick_400b",
     "phi3.5-moe-42b-a6.6b": "phi3p5_moe_42b",
     "paligemma-3b": "paligemma_3b",
+    "lfm2-8b-a1b": "lfm2_8b_a1b",
 }
 
 CNN_ARCHS = {

@@ -620,7 +620,7 @@ def run(argv=None):
         # scheduler; with a --drift-schedule the chip ages (and refreshes)
         # BETWEEN decode steps of this single run via the DriftPolicy.
         trace = request_trace(args, cfg)
-        if cfg.family == "moe":
+        if cfg.family == "moe" and cfg.moe_dispatch != "dropless":
             print("warning: MoE capacity routing pools tokens across the "
                   "decode batch, so continuous-batching generations are "
                   "not bit-identical to solo serving for this family",

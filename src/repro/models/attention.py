@@ -26,7 +26,9 @@ from jax.extend import core as jex_core
 from jax.interpreters import mlir
 
 from repro.core.analog import AnalogCtx, linear_init, proj
-from repro.models.common import ModelConfig, rope, shard
+from repro.models.common import (
+    ModelConfig, rmsnorm_apply, rmsnorm_init, rope, shard,
+)
 
 Array = jax.Array
 
@@ -46,12 +48,16 @@ class KVCache(NamedTuple):
 def attn_init(key: Array, cfg: ModelConfig) -> dict:
     kq, kk, kv, ko = jax.random.split(key, 4)
     hd, nh, nkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
-    return {
+    params = {
         "wq": linear_init(kq, cfg.d_model, nh * hd, use_bias=cfg.qkv_bias),
         "wk": linear_init(kk, cfg.d_model, nkv * hd, use_bias=cfg.qkv_bias),
         "wv": linear_init(kv, cfg.d_model, nkv * hd, use_bias=cfg.qkv_bias),
         "wo": linear_init(ko, nh * hd, cfg.d_model),
     }
+    if cfg.qk_norm:
+        params["q_norm"] = rmsnorm_init(cfg, hd)
+        params["k_norm"] = rmsnorm_init(cfg, hd)
+    return params
 
 
 def _split_heads(x: Array, n: int, hd: int) -> Array:
@@ -234,9 +240,13 @@ class PagedKVCache(NamedTuple):
     slot whose table row starts with page 0 holds no request: every live
     slot's first page is real.
 
-    The pools are head-major, ``(n_kv, n_pages, page_size, hd)``: one kv
-    head's page is one contiguous ``(page_size, hd)`` block, the unit the
-    TPU's paged-attention kernel copies. Decode attention over the cache
+    The pools are head-major, ``(n_kv, n_pages, page_size, hd_pool)``: one
+    kv head's page is one contiguous ``(page_size, hd_pool)`` block, the
+    unit the TPU's paged-attention kernel copies. For a pool made for a TPU
+    ``hd_pool`` is the head size rounded up to a multiple of 128
+    (:func:`pool_head_dim`), the lane width the kernel blocks its outputs
+    by; the padding holds zeros and no path reads it into a result.
+    Decode attention over the cache
     (:func:`paged_decode_attention`) takes one of two paths, chosen by the
     backend the program is compiled for and the cache's sharding:
 
@@ -249,8 +259,8 @@ class PagedKVCache(NamedTuple):
       this path is bitwise identical to the rectangular slot cache.
     """
 
-    k: Array  # (n_kv, n_pages, page_size, hd) -- pool shared by all slots
-    v: Array  # (n_kv, n_pages, page_size, hd)
+    k: Array  # (n_kv, n_pages, page_size, hd_pool) -- shared by all slots
+    v: Array  # (n_kv, n_pages, page_size, hd_pool)
     table: Array  # (B, pages_per_slot) int32 page ids; 0 = scratch page
     length: Array  # (B,) int32 tokens written per slot
     #: zero-element (s_max, 0) buffer: shape-encodes the slot's virtual
@@ -268,6 +278,27 @@ class PagedKVCache(NamedTuple):
         return self.cap_buf.shape[0]
 
 
+def pool_head_dim(hd: int, platform: Optional[str] = None) -> int:
+    """A page pool's head size on ``platform`` (default: this process's
+    backend). Decode attention lowers to the Pallas paged-attention kernel
+    for a TPU and to the view path elsewhere (:func:`paged_decode_attention`),
+    so the rule follows the same target: for a TPU, ``hd`` rounded up to a
+    multiple of 128, since the kernel blocks its per-head softmax
+    statistics by the head size, which Mosaic takes only as a multiple of
+    128 (LFM2's 64-wide heads are padded, OLMo's 128 are not); elsewhere
+    ``hd``, which keeps a narrow-headed pool as small as its tokens (the
+    view path also reads a padded pool, bitwise as an unpadded one)."""
+    if (platform or jax.default_backend()) != "tpu":
+        return hd
+    return -(-hd // 128) * 128
+
+
+def pad_head(x: Array, width: int) -> Array:
+    """``x`` with its last (head) axis zero-padded to ``width``."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
 def init_paged_cache(
     cfg: ModelConfig,
     batch: int,
@@ -276,10 +307,11 @@ def init_paged_cache(
     *,
     page_size: int,
     n_pages: int,
+    platform: Optional[str] = None,
 ) -> PagedKVCache:
     """One layer's page pool + per-slot tables (pool id space is shared
     across layers: the serving allocator hands out one page id that is
-    valid in every layer's pool)."""
+    valid in every layer's pool). ``platform``: :func:`pool_head_dim`."""
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     pages_per_slot = -(-s_max // page_size)
@@ -291,7 +323,8 @@ def init_paged_cache(
     # NOTE: n_pages may be much smaller than batch * pages_per_slot (that
     # is the point: s_max is VIRTUAL capacity); the serving engine's
     # admission reservations keep actual usage within the pool.
-    shape = (cfg.n_kv_heads, n_pages, page_size, cfg.hd)
+    shape = (cfg.n_kv_heads, n_pages, page_size,
+             pool_head_dim(cfg.hd, platform))
     return PagedKVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
@@ -306,7 +339,8 @@ def paged_view(cache: PagedKVCache) -> KVCache:
     rectangular (B, s_max, n_kv, hd) slot-cache view.
 
     Pure data movement (gather + transpose + reshape + slice, no
-    arithmetic), sliced to the shape-encoded virtual capacity: attention
+    arithmetic), sliced to the shape-encoded virtual capacity (the head
+    axis keeps the pool's padding, :func:`pool_head_dim`): attention
     over the view is bitwise identical to attention over a rectangular slot
     cache holding the same tokens. Positions past a slot's length read
     scratch/garbage rows and are masked to exact-zero probability by
@@ -362,10 +396,11 @@ def paged_attention_kernel(q: Array, cache: PagedKVCache) -> Array:
     n = jnp.where(live, jnp.minimum(cache.length, cache.s_max), 0)
     used = jnp.arange(pages_per_slot)[None, :] < -(-n // ps)[:, None]
     table = jnp.where(used, cache.table, cache.table[:, :1])
+    qs = pad_head(q[:, 0].astype(jnp.float32) * d**-0.5, cache.k.shape[-1])
     out = paged_attention(
-        q[:, 0].astype(jnp.float32) * d**-0.5, cache.k, cache.v, n, table,
+        qs, cache.k, cache.v, n, table,
         pages_per_compute_block=kernel_block_pages(ps, pages_per_slot),
-    )
+    )[..., :d]
     out = jnp.where(live[:, None, None], out, 0.0)
     return out[:, None].astype(q.dtype)
 
@@ -383,6 +418,9 @@ def _lowering(path):
 def _view_path(q: Array, cache: PagedKVCache) -> Array:
     with jax.named_scope("paged_view"):
         view = paged_view(cache)
+        hd = q.shape[-1]
+        if view.k.shape[-1] != hd:  # a padded pool (pool_head_dim)
+            view = KVCache(view.k[..., :hd], view.v[..., :hd], view.length)
     with jax.named_scope("scores"):
         return decode_attention(q, view)
 
@@ -455,6 +493,9 @@ def attn_apply(
     q = _split_heads(proj(params, "wq", x, ctx), nh, hd)
     k = _split_heads(proj(params, "wk", x, ctx), nkv, hd)
     v = _split_heads(proj(params, "wv", x, ctx), nkv, hd)
+    if cfg.qk_norm:  # per-head RMSNorm of q and k, before RoPE
+        q = rmsnorm_apply(params["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm_apply(params["k_norm"], k, cfg.norm_eps)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     q = shard(q, "batch", None, "heads", None)
@@ -486,6 +527,7 @@ def attn_apply(
             # the TPU compiler lay the pool out page-major and copy all of
             # it to and from the kernel's head-major layout every step
             rows = x[:, 0].swapaxes(0, 1)[:, :, None].astype(pool.dtype)
+            rows = pad_head(rows, pool.shape[-1])
             pages = jnp.where(at_off[None, :, :, None], rows, pool[:, page])
             return pool.at[:, page].set(pages)
 

@@ -71,11 +71,17 @@ class ModelConfig:
     # MoE
     n_experts: int = 0
     top_k: int = 0
+    moe_d_ff: int = 0  # an expert's FFN width, where not d_ff (LFM2)
     moe_every: int = 1  # an MoE FFN every N layers (llama4 interleaves: 2)
     shared_expert: bool = False
     capacity_factor: float = 1.25
     moe_groups: int = 16  # dispatch groups (GShard-style); >= data shards
-    moe_dispatch: str = "einsum"  # einsum (GShard one-hot) | scatter (indexed)
+    # einsum (GShard one-hot) | scatter (indexed) | shard_map: capacity-
+    # bounded dispatch, softmax top-k; dropless: every held expert's rows
+    # (the LFM2 router: sigmoid scores, top-k by score + expert bias)
+    moe_dispatch: str = "einsum"
+    #: ids of the experts this chip holds (dropless dispatch); () = all
+    moe_held: tuple = ()
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -84,10 +90,14 @@ class ModelConfig:
     conv_width: int = 4
     # hybrid (recurrentgemma / griffin)
     block_pattern: tuple = ()  # e.g. ("rec", "rec", "attn")
+    #: one block kind per layer, for patterns no period repeats (LFM2:
+    #: "conv", "attn", "conv_moe", "moe"); every layer runs unscanned
+    layer_kinds: tuple = ()
     local_window: int = 2048
     lru_width: int = 0  # 0 -> d_model
     # flavor flags
     qkv_bias: bool = False  # qwen2
+    qk_norm: bool = False  # per-head RMSNorm of q and k before RoPE (LFM2)
     nonparametric_ln: bool = False  # olmo
     n_codebooks: int = 0  # musicgen parallel heads
     rope_theta: float = 10000.0
@@ -116,17 +126,23 @@ class ModelConfig:
 
     def smoke(self) -> "ModelConfig":
         """Reduced config of the same family for CPU smoke tests."""
+        n_layers = max(2, len(self.block_pattern) or 2)
+        if self.layer_kinds:  # the shortest prefix holding every kind
+            kinds = self.layer_kinds
+            n_layers = 1 + max(kinds.index(k) for k in set(kinds))
         return dataclasses.replace(
             self,
-            n_layers=max(2, len(self.block_pattern) or 2),
+            n_layers=n_layers,
             d_model=64,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads, 2)),
             d_ff=128,
+            moe_d_ff=96 if self.moe_d_ff else 0,
             vocab=256,
             head_dim=16,
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            moe_held=(),
             moe_groups=2,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=16,

@@ -8,12 +8,20 @@ Families (ModelConfig.family):
   hybrid  -- recurrentgemma-9b (2x RG-LRU : 1x local attention)
   audio   -- musicgen-large (decoder over EnCodec frames; frontend stubbed)
   vlm     -- paligemma-3b (SigLIP patches stubbed, gemma decoder)
+  lfm2-8b-a1b (moe) -- gated short-conv and GQA layers, dense then MoE
+             FFNs, in an irregular per-layer order (``cfg.layer_kinds``)
 
 Structure: layers are grouped into the architecture's repeating *period*
 (dense: [attn]; llama4: [attn, moe]; recurrentgemma: [rec, rec, attn]) and
 the period-group stack is evaluated with lax.scan -- essential to keep HLO
 size and compile time bounded at 80-layer/512-device scale. Layers left over
 when n_layers % period != 0 run unscanned (recurrentgemma: 38 = 12*3 + 2).
+A per-layer kind list (``cfg.layer_kinds``) has no period: every layer runs
+unscanned, each with its own (unstacked) parameters.
+
+Block kinds: ``attn`` (attention + SwiGLU FFN), ``moe`` (attention + MoE),
+``conv`` (gated short conv + SwiGLU FFN), ``conv_moe`` (short conv + MoE),
+``rec`` / ``lattn`` (hybrid), ``ssm``.
 
 Every projection is an AnalogLinear: the paper's noise-injection + DAC/ADC
 training and PCM inference apply to the full LM family through the same
@@ -40,6 +48,7 @@ from repro.core.analog import (
 from repro.models import attention as attn_lib
 from repro.models import griffin as griffin_lib
 from repro.models import moe as moe_lib
+from repro.models import shortconv as conv_lib
 from repro.models import ssm as ssm_lib
 from repro.models.common import (
     ModelConfig,
@@ -58,7 +67,16 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 
 
+#: block kinds whose FFN is a mixture of experts / whose mixer is the
+#: gated short conv
+MOE_KINDS = frozenset({"moe", "conv_moe"})
+CONV_KINDS = frozenset({"conv", "conv_moe"})
+
+
 def block_period(cfg: ModelConfig) -> list[str]:
+    """The repeating block pattern (a per-layer kind list: all of it)."""
+    if cfg.layer_kinds:
+        return list(cfg.layer_kinds[: cfg.n_layers])
     if cfg.family == "ssm":
         return ["ssm"]
     if cfg.family == "hybrid":
@@ -68,6 +86,24 @@ def block_period(cfg: ModelConfig) -> list[str]:
             return ["moe"]
         return ["attn"] * (cfg.moe_every - 1) + ["moe"]
     return ["attn"]  # dense / audio / vlm
+
+
+def layer_layout(cfg: ModelConfig) -> tuple[list[str], int, list[str]]:
+    """(scanned period, its repeat count, kinds of the unscanned layers).
+
+    A per-layer kind list (``cfg.layer_kinds``) scans nothing."""
+    if cfg.layer_kinds:
+        return [], 0, block_period(cfg)
+    period = block_period(cfg)
+    n_groups = cfg.n_layers // len(period)
+    n_tail = cfg.n_layers - n_groups * len(period)
+    return period, n_groups, [period[i % len(period)] for i in range(n_tail)]
+
+
+def layer_kinds(cfg: ModelConfig) -> list[str]:
+    """The block kind of every layer, in order."""
+    period, n_groups, tail = layer_layout(cfg)
+    return period * n_groups + tail
 
 
 def mlp_init(key: Array, cfg: ModelConfig) -> dict:
@@ -98,6 +134,12 @@ def _block_init(key: Array, kind: str, cfg: ModelConfig) -> dict:
     elif kind == "moe":
         params["attn"] = attn_lib.attn_init(km, cfg)
         params["moe"] = moe_lib.moe_init(kf, cfg)
+    elif kind in CONV_KINDS:
+        params["conv"] = conv_lib.conv_init(km, cfg)
+        if kind == "conv_moe":
+            params["moe"] = moe_lib.moe_init(kf, cfg)
+        else:
+            params["ffn"] = mlp_init(kf, cfg)
     elif kind == "rec":
         params["rec"] = griffin_lib.griffin_init(km, cfg)
         params["ffn"] = mlp_init(kf, cfg)
@@ -134,23 +176,38 @@ def _block_apply(
     positions: Array,
     cache,
     layer_idx=None,
+    last_index=None,
+    valid=None,
 ):
     """One block: norm -> mixer -> residual [-> norm -> ffn -> residual].
 
+    Returns (x, new cache, stats): ``stats`` is a dropless MoE layer's
+    count vector (``moe.STAT_NAMES``), else None.
+
     ``layer_idx``: when set, ``cache`` is layer-stacked (decode unrolled
     path); attention writes the new token into the stacked buffer in place,
-    while the small SSM/RG-LRU states use slice + write-back.
+    while the small SSM/RG-LRU/conv states use slice + write-back.
+    ``last_index`` (B,): each row's last real position in a right-padded
+    prefill, where a conv state is taken. ``valid`` (B, S): the real rows
+    a dropless MoE layer counts (:func:`_real_rows`).
     """
     h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps)
     if kind == "ssm":
         out, nc = ssm_lib.ssm_apply(
             params["ssm"], h, ctx, cfg, _slice_cache(cache, layer_idx)
         )
-        return x + out, _writeback_cache(cache, nc, layer_idx)
+        return x + out, _writeback_cache(cache, nc, layer_idx), None
     if kind == "rec":
         out, nc = griffin_lib.griffin_apply(
             params["rec"], h, ctx, cfg, _slice_cache(cache, layer_idx)
         )
+        new_cache = _writeback_cache(cache, nc, layer_idx)
+    elif kind in CONV_KINDS:
+        with jax.named_scope("conv"):
+            out, nc = conv_lib.conv_apply(
+                params["conv"], h, ctx, _slice_cache(cache, layer_idx),
+                last_index,
+            )
         new_cache = _writeback_cache(cache, nc, layer_idx)
     else:
         window = cfg.local_window if cfg.family == "hybrid" else None
@@ -161,7 +218,14 @@ def _block_apply(
             )
     x = x + out
     h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
-    if kind == "moe":
+    stats = None
+    if kind in MOE_KINDS and cfg.moe_dispatch == "dropless":
+        with jax.named_scope("moe"):
+            y, stats = moe_lib.moe_apply_dropless(
+                params["moe"], h, ctx, cfg, valid
+            )
+        x = x + y
+    elif kind in MOE_KINDS:
         if cfg.moe_dispatch == "shard_map":
             from repro.models.moe_shardmap import moe_apply_shardmap
 
@@ -171,7 +235,37 @@ def _block_apply(
     else:
         with jax.named_scope("mlp"):
             x = x + mlp_apply(params["ffn"], h, ctx)
-    return x, new_cache
+    return x, new_cache, stats
+
+
+def _merge_stats(parts) -> Array:
+    """The dropless MoE layers' count vectors (``moe.STAT_NAMES``, leading
+    axes over scanned groups) as one: sums, but the max-expert count's max.
+    Empty (0,) where no layer made one."""
+    n = len(moe_lib.STAT_NAMES)
+    parts = [p.reshape(-1, n) for p in parts if p is not None]
+    if not parts:
+        return jnp.zeros((0,), jnp.int32)
+    allp = jnp.concatenate(parts)
+    return jnp.concatenate(
+        [allp[:, :2].sum(0), allp[:, 2:3].max(0), allp[:, 3:].sum(0)]
+    ).astype(jnp.int32)
+
+
+def _real_rows(cache, b: int, s: int, last_index) -> Optional[Array]:
+    """(B, S) bool: the rows that hold a request's token, or None for all.
+
+    A right-padded prefill's row is real up to its ``last_index``; in a
+    decode step over a paged cache, a slot whose table row starts with the
+    scratch page holds no request (:class:`attention.PagedKVCache`)."""
+    if last_index is not None:
+        return jnp.arange(s)[None, :] <= jnp.asarray(last_index)[:, None]
+    pools = [
+        x for x in jax.tree.leaves(cache, is_leaf=_is_paged) if _is_paged(x)
+    ]
+    if s == 1 and pools:
+        return jnp.broadcast_to((pools[0].table[:, 0] != 0)[:, None], (b, 1))
+    return None
 
 
 def _block_cache(
@@ -180,18 +274,22 @@ def _block_cache(
     paged: bool = False,
     page_size: int = 16,
     n_pages: int = 0,
+    platform: Optional[str] = None,
 ):
     if kind == "ssm":
         return ssm_lib.init_ssm_cache(cfg, batch, dtype)
     if kind == "rec":
         return griffin_lib.init_rglru_cache(cfg, batch, dtype)
+    if kind in CONV_KINDS:
+        return conv_lib.init_conv_state(cfg, batch, dtype)
     # local attention needs only a window-sized cache; decode_32k/long_500k
     # feasibility for the hybrid family rests on this bound.
     if cfg.family == "hybrid":
         s_max = min(s_max, cfg.local_window)
     if paged:
         return attn_lib.init_paged_cache(
-            cfg, batch, s_max, dtype, page_size=page_size, n_pages=n_pages
+            cfg, batch, s_max, dtype, page_size=page_size, n_pages=n_pages,
+            platform=platform,
         )
     return attn_lib.init_cache(cfg, batch, s_max, dtype, per_slot=per_slot)
 
@@ -212,21 +310,20 @@ class LMParams(NamedTuple):
 
 
 def lm_init(key: Array, cfg: ModelConfig) -> LMParams:
-    period = block_period(cfg)
-    n_groups = cfg.n_layers // len(period)
-    n_tail = cfg.n_layers - n_groups * len(period)
+    period, n_groups, tail_kinds = layer_layout(cfg)
     k_embed, k_blocks, k_tail, k_head, k_extra = jax.random.split(key, 5)
 
     def init_group(gk: Array) -> tuple:
         keys = jax.random.split(gk, len(period))
         return tuple(_block_init(keys[i], kind, cfg) for i, kind in enumerate(period))
 
-    group_keys = jax.random.split(k_blocks, n_groups)
-    blocks = jax.vmap(init_group)(group_keys)
+    blocks = ()
+    if n_groups:
+        blocks = jax.vmap(init_group)(jax.random.split(k_blocks, n_groups))
 
     tail = tuple(
-        _block_init(jax.random.fold_in(k_tail, i), period[i % len(period)], cfg)
-        for i in range(n_tail)
+        _block_init(jax.random.fold_in(k_tail, i), kind, cfg)
+        for i, kind in enumerate(tail_kinds)
     )
 
     extras: dict[str, Any] = {}
@@ -271,8 +368,12 @@ def lm_forward(
     cache: Optional[tuple] = None,
     last_token_only: bool = False,
     last_index: Optional[Array] = None,
+    moe_stats: bool = False,
 ):
-    """Forward pass. Returns (logits, new_cache).
+    """Forward pass. Returns (logits, new_cache) -- and, with
+    ``moe_stats``, the dropless MoE layers' merged count vector
+    (``moe.STAT_NAMES``, counting the real rows of :func:`_real_rows` as
+    tokens; (0,) for a model without such layers).
 
     ``cache`` is (stacked_group_caches, tail_caches) or None. When
     ``last_token_only`` (prefill serving), only the final position's logits
@@ -280,9 +381,10 @@ def lm_forward(
     hundreds of GB. ``last_index`` (a (B,) int vector, requires
     ``last_token_only``) picks each row's logit position explicitly --
     bucketed prefill right-pads prompts to a shared length, so row ``i``'s
-    real last token sits at ``len_i - 1``, not at ``-1``.
+    real last token sits at ``len_i - 1``, not at ``-1``. It is also where
+    each row's conv state is taken.
     """
-    period = block_period(cfg)
+    period, n_groups, tail_kinds = layer_layout(cfg)
     ctx0 = AnalogCtx(cfg=analog_cfg, gain_s=params.gain_s, key=rng)
     with jax.named_scope("embed"):
         h = _embed_inputs(params, batch, cfg, ctx0)
@@ -301,6 +403,7 @@ def lm_forward(
         positions = start[:, None] + jnp.arange(s)[None, :]
     else:
         positions = start + jnp.arange(s)[None, :]  # (1, S) broadcasts
+    valid = _real_rows(cache, b, s, last_index)
 
     def group_fn(h, group_params, group_cache, group_idx):
         ctx = AnalogCtx(
@@ -308,38 +411,40 @@ def lm_forward(
             gain_s=params.gain_s,
             key=None if rng is None else jax.random.fold_in(rng, group_idx),
         )
-        new_caches = []
+        new_caches, stats = [], []
         for i, kind in enumerate(period):
             blk_cache = None if group_cache is None else group_cache[i]
-            h, nc = _block_apply(
-                group_params[i], kind, h, ctx, cfg, positions, blk_cache
+            h, nc, st = _block_apply(
+                group_params[i], kind, h, ctx, cfg, positions, blk_cache,
+                last_index=last_index, valid=valid,
             )
             new_caches.append(nc)
+            stats.append(st)
         # Megatron-SP-style: the scan carry (== the per-layer residual saved
         # for the rematerialised backward) lives sequence-sharded over the
         # model axis; GSPMD inserts the gather at the next block's first use.
         h = shard(h, "batch", "seq", None)
-        return h, tuple(new_caches)
+        return h, (tuple(new_caches), tuple(stats))
 
     if cfg.remat:
         group_fn = jax.checkpoint(group_fn, static_argnums=())
 
-    n_groups = cfg.n_layers // len(period)
+    all_stats = []
     if n_groups > 0:
         idxs = jnp.arange(n_groups)
 
         def scan_body(h, xs):
             gp, gc, gi = xs
-            h, nc = group_fn(h, gp, gc, gi)
-            return h, nc
+            return group_fn(h, gp, gc, gi)
 
         if group_caches is None:
-            # dummy per-group cache slot so the scan signature is static
-            h, _ = jax.lax.scan(
-                lambda hh, xs: group_fn(hh, xs[0], None, xs[1])[:1] + ((),),
-                h,
-                (params.blocks, idxs),
-            )
+            # no cache: the scan carries h and collects the counts only
+            def no_cache_body(hh, xs):
+                hh, (_, st) = group_fn(hh, xs[0], None, xs[1])
+                return hh, st
+
+            h, g_stats = jax.lax.scan(no_cache_body, h, (params.blocks, idxs))
+            all_stats.extend(g_stats)
             new_group_caches = None
         elif isinstance(group_caches, list) or s == 1:
             # Decode: an unrolled layer loop where each layer updates only
@@ -361,39 +466,44 @@ def lm_forward(
                 new_gc = []
                 for i, kind in enumerate(period):
                     if unstacked:
-                        h, nc = _block_apply(
+                        h, nc, st = _block_apply(
                             gp[i], kind, h, ctx_g, cfg, positions,
-                            group_caches[gi][i],
+                            group_caches[gi][i], valid=valid,
                         )
                     else:
-                        h, nc = _block_apply(
+                        h, nc, st = _block_apply(
                             gp[i], kind, h, ctx_g, cfg, positions,
-                            gc_cur[i], layer_idx=gi,
+                            gc_cur[i], layer_idx=gi, valid=valid,
                         )
                     new_gc.append(nc)
+                    all_stats.append(st)
                 if unstacked:
                     new_gcs.append(tuple(new_gc))
                 else:
                     gc_cur = tuple(new_gc)
             new_group_caches = new_gcs if unstacked else gc_cur
         else:
-            h, new_group_caches = jax.lax.scan(
+            h, (new_group_caches, g_stats) = jax.lax.scan(
                 scan_body, h, (params.blocks, group_caches, idxs)
             )
+            all_stats.extend(g_stats)
     else:
         new_group_caches = group_caches
 
     new_tail_caches = []
-    for i, tp in enumerate(params.tail):
-        kind = period[i % len(period)]
+    for i, (tp, kind) in enumerate(zip(params.tail, tail_kinds)):
         ctx = AnalogCtx(
             cfg=analog_cfg,
             gain_s=params.gain_s,
             key=None if rng is None else jax.random.fold_in(rng, 10_000 + i),
         )
         tc = None if tail_caches is None else tail_caches[i]
-        h, nc = _block_apply(tp, kind, h, ctx, cfg, positions, tc)
+        h, nc, st = _block_apply(
+            tp, kind, h, ctx, cfg, positions, tc, last_index=last_index,
+            valid=valid,
+        )
         new_tail_caches.append(nc)
+        all_stats.append(st)
 
     with jax.named_scope("head"):
         h = rmsnorm_apply(params.final_norm, h, cfg.norm_eps)
@@ -411,6 +521,8 @@ def lm_forward(
     new_cache = None
     if cache is not None:
         new_cache = (new_group_caches, tuple(new_tail_caches))
+    if moe_stats:
+        return logits, new_cache, _merge_stats(all_stats)
     return logits, new_cache
 
 
@@ -465,6 +577,7 @@ def init_lm_cache(
     paged: bool = False,
     page_size: int = 16,
     n_pages: Optional[int] = None,
+    platform: Optional[str] = None,
 ) -> tuple:
     """Build the (group caches, tail caches) pytree.
 
@@ -487,7 +600,11 @@ def init_lm_cache(
     slots plus the reserved scratch page 0), with ``s_max`` the per-slot
     *virtual* capacity. Slot admission/retirement goes through
     :func:`write_cache_slot_paged` / :func:`free_cache_slot_paged` with
-    page ids handed out by the serving engine's allocator.
+    page ids handed out by the serving engine's allocator. A short-conv
+    layer's state is per slot, (batch, k - 1, d), beside the pools.
+    ``platform``: where the pools will live and decode will run (default:
+    this process's backend), which sets their head size
+    (:func:`repro.models.attention.pool_head_dim`).
     """
     if per_slot and stacked:
         raise ValueError(
@@ -500,26 +617,26 @@ def init_lm_cache(
                 "paged caches use the unstacked decode layout "
                 "(pass stacked=False)"
             )
-        kinds = set(block_period(cfg))
-        if not kinds <= {"attn", "moe"}:
+        refused = set(layer_kinds(cfg)) - {"attn", "moe"} - CONV_KINDS
+        if refused:
             raise ValueError(
-                "paged serving supports attention-cache families only "
-                f"(family={cfg.family!r} has blocks {sorted(kinds)}): "
-                "SSM/RG-LRU recurrent state is position-free, so the "
-                "right-padded bucketed prefill that paging relies on would "
-                "fold pad tokens into it"
+                "paged serving holds attention pages and short-conv state; "
+                f"the {sorted(refused)} blocks of family {cfg.family!r} "
+                "are still refused: their SSM / RG-LRU scan state is "
+                "position-free (taken at the sequence's end, not at each "
+                "row's last real token), so the right-padded bucketed "
+                "prefill would fold pad tokens into it"
             )
         if n_pages is None:
             n_pages = batch * (-(-s_max // page_size)) + 1
-    period = block_period(cfg)
-    n_groups = cfg.n_layers // len(period)
-    n_tail = cfg.n_layers - n_groups * len(period)
+    period, n_groups, tail_kinds = layer_layout(cfg)
 
     def one_group():
         return tuple(
             _block_cache(
                 kind, cfg, batch, s_max, dtype, per_slot=per_slot,
                 paged=paged, page_size=page_size, n_pages=n_pages or 0,
+                platform=platform,
             )
             for kind in period
         )
@@ -533,11 +650,12 @@ def init_lm_cache(
         groups = [one_group() for _ in range(n_groups)]
     tail = tuple(
         _block_cache(
-            period[i % len(period)], cfg, batch, s_max, dtype,
+            kind, cfg, batch, s_max, dtype,
             per_slot=per_slot,
             paged=paged, page_size=page_size, n_pages=n_pages or 0,
+            platform=platform,
         )
-        for i in range(n_tail)
+        for kind in tail_kinds
     )
     return groups, tail
 
@@ -616,6 +734,9 @@ def reset_cache_slot(cache: tuple, slot) -> tuple:
 # ---------------------------------------------------------------------------
 
 _is_paged = lambda x: isinstance(x, attn_lib.PagedKVCache)
+_is_slot_leaf = lambda x: isinstance(
+    x, (attn_lib.PagedKVCache, conv_lib.ConvState)
+)
 
 
 def write_cache_slot_paged(
@@ -632,17 +753,23 @@ def write_cache_slot_paged(
     0, so the pad-region rows of a short prompt land in the scratch page
     instead of costing real pages. ``length`` is the request's true token
     count; decode masks everything past it, so pad-position K/V inside
-    the slot's last real page is inert.
+    the slot's last real page is inert. A conv state is copied from row
+    ``row`` (the prefill took it at the row's true last position).
     """
     pages = jnp.asarray(pages, jnp.int32)
     nbp = pages.shape[0]
     length = jnp.asarray(length, jnp.int32)
 
-    def write(dst: attn_lib.PagedKVCache, s_leaf: attn_lib.KVCache):
+    def write(dst, s_leaf):
+        if isinstance(dst, conv_lib.ConvState):
+            return conv_lib.ConvState(
+                dst.x.at[slot].set(s_leaf.x[row].astype(dst.x.dtype))
+            )
         ps = dst.page_size
 
         def scatter(pool, rows):
             rows = rows[row].astype(pool.dtype)  # (S_bucket, kv, hd)
+            rows = attn_lib.pad_head(rows, pool.shape[-1])
             pad = nbp * ps - rows.shape[0]
             if pad:
                 rows = jnp.pad(rows, ((0, pad), (0, 0), (0, 0)))
@@ -659,7 +786,7 @@ def write_cache_slot_paged(
             length=dst.length.at[slot].set(length),
         )
 
-    return jax.tree.map(write, cache, src, is_leaf=_is_paged)
+    return jax.tree.map(write, cache, src, is_leaf=_is_slot_leaf)
 
 
 def append_cache_page(cache: tuple, slot, entry, page) -> tuple:
@@ -670,14 +797,17 @@ def append_cache_page(cache: tuple, slot, entry, page) -> tuple:
     slot's length are masked), so no zeroing is needed on append.
     """
 
-    def app(dst: attn_lib.PagedKVCache):
+    def app(dst):
+        if not _is_paged(dst):
+            return dst
         return dst._replace(table=dst.table.at[slot, entry].set(page))
 
-    return jax.tree.map(app, cache, is_leaf=_is_paged)
+    return jax.tree.map(app, cache, is_leaf=_is_slot_leaf)
 
 
 def free_cache_slot_paged(cache: tuple, slot, pages) -> tuple:
-    """Retire slot ``slot``: zero its pages, table row, and length.
+    """Retire slot ``slot``: zero its pages, table row, length and conv
+    states.
 
     ``pages`` is a fixed-width (pages_per_slot,) int32 vector -- the slot's
     real page ids padded with 0s (re-zeroing the scratch page is harmless).
@@ -687,7 +817,11 @@ def free_cache_slot_paged(cache: tuple, slot, pages) -> tuple:
     """
     pages = jnp.asarray(pages, jnp.int32)
 
-    def free(dst: attn_lib.PagedKVCache):
+    def free(dst):
+        if isinstance(dst, conv_lib.ConvState):
+            return conv_lib.ConvState(
+                dst.x.at[slot].set(jnp.zeros(dst.x.shape[1:], dst.x.dtype))
+            )
         n_kv, _, ps, hd = dst.k.shape
         z = jnp.zeros((n_kv, pages.shape[0], ps, hd), dst.k.dtype)
         return dst._replace(
@@ -697,7 +831,7 @@ def free_cache_slot_paged(cache: tuple, slot, pages) -> tuple:
             length=dst.length.at[slot].set(0),
         )
 
-    return jax.tree.map(free, cache, is_leaf=_is_paged)
+    return jax.tree.map(free, cache, is_leaf=_is_slot_leaf)
 
 
 # ---------------------------------------------------------------------------

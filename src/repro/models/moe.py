@@ -13,9 +13,20 @@ shared r_ADC (the paper's per-layer fixed-gain constraint; experts within a
 layer share the physical ADC configuration). The *router* stays digital: it
 is exactly the narrow, noise-sensitive bottleneck the paper removes from its
 models (Sec. 4.1 "small layers are bottlenecks") -- see DESIGN.md.
+
+Dropless dispatch (``cfg.moe_dispatch == "dropless"``, the inference and
+serving path of LFM2): the layer holds a share of the experts
+(``cfg.moe_held``), routes every token over all ``cfg.n_experts`` with
+LFM2's rule (:func:`route_sigmoid_bias`), and runs every held expert's
+programmed bank over every row, weighting each row by its routing weight
+for that expert (0 where the token did not choose it). No token is dropped
+and no row depends on another, so a batch's rows are independent. The
+capacity paths stay for training.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,18 +39,37 @@ from repro.models.common import ModelConfig, shard
 Array = jax.Array
 
 
+#: the dropless layer's counts, in the order of its stats vector: (token,
+#: held expert) pairs routed, rows the expert MVMs ran (padding included),
+#: the most tokens one held expert was routed, and the tokens the layer
+#: took. A token is a real row: not a pad position of a right-padded
+#: prefill, nor an idle decode slot (the ``valid`` mask the layer is given)
+STAT_NAMES = (
+    "moe.rows_held", "moe.rows_computed", "moe.rows_max_expert", "moe.tokens"
+)
+
+
+def held_experts(cfg: ModelConfig) -> tuple:
+    """The ids of the experts this layer holds (all when none are named)."""
+    return tuple(cfg.moe_held) or tuple(range(cfg.n_experts))
+
+
 def moe_init(key: Array, cfg: ModelConfig) -> dict:
-    e, m, h = cfg.n_experts, cfg.d_model, cfg.d_ff
+    e, m, h = len(held_experts(cfg)), cfg.d_model, cfg.moe_d_ff or cfg.d_ff
     k1, k2, k3, kr, ks = jax.random.split(key, 5)
     s_in, s_h = m**-0.5, h**-0.5
     params = {
-        "router": {"w": jax.random.normal(kr, (m, e), jnp.float32) * s_in},
+        "router": {
+            "w": jax.random.normal(kr, (m, cfg.n_experts), jnp.float32) * s_in
+        },
         "w1": jax.random.normal(k1, (e, m, h), jnp.float32) * s_in,
         "w3": jax.random.normal(k3, (e, m, h), jnp.float32) * s_in,
         "w2": jax.random.normal(k2, (e, h, m), jnp.float32) * s_h,
         "r_adc": jnp.ones((3,), jnp.float32),  # per matmul family (w1,w3,w2)
         "w_clip_buf": jnp.tile(jnp.array([-1.0, 1.0], jnp.float32), (3, 1)),
     }
+    if cfg.moe_dispatch == "dropless":
+        params["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     if cfg.shared_expert:
         from repro.core.analog import linear_init
 
@@ -56,6 +86,9 @@ def _expert_ffn(
     params: dict, x: Array, ctx: AnalogCtx, dtype, b_adc=None
 ) -> Array:
     """x: (E, G, C, M) -> (E, G, C, M); SwiGLU per expert, analog-mapped.
+
+    Each MVM runs in the bank's own dtype (f32 effective weights in a
+    programmed chip), as every programmed linear layer does.
 
     ``out_scale_buf`` (3, E) carries per-(family, expert) GDC scalars when
     the expert bank was programmed by ``engine.compile_program``; otherwise
@@ -89,7 +122,7 @@ def _expert_ffn(
     def one_expert(w1, w3, w2, clip1, clip3, clip2, s, xe):
         h1 = analog_matmul(
             xe,
-            w1.astype(dtype),
+            w1,
             r_adc=params["r_adc"][0],
             w_min=clip1[0],
             w_max=clip1[1],
@@ -99,7 +132,7 @@ def _expert_ffn(
         )
         h3 = analog_matmul(
             xe,
-            w3.astype(dtype),
+            w3,
             r_adc=params["r_adc"][1],
             w_min=clip3[0],
             w_max=clip3[1],
@@ -110,7 +143,7 @@ def _expert_ffn(
         h = jax.nn.silu(h1) * h3
         return analog_matmul(
             h,
-            w2.astype(dtype),
+            w2,
             r_adc=params["r_adc"][2],
             w_min=clip2[0],
             w_max=clip2[1],
@@ -238,6 +271,77 @@ def moe_apply(params: dict, x: Array, ctx: AnalogCtx, cfg: ModelConfig) -> Array
         y = y + shared_expert_apply(params, xt, ctx)
 
     return y.reshape(b, s, m)
+
+
+def route_sigmoid_bias(logits: Array, bias: Array, k: int):
+    """LFM2's routing of f32 router logits (T, E) over every expert.
+
+    Scores are ``sigmoid(logits)``; the ``k`` experts with the highest
+    ``score + bias`` are chosen, ties to the lowest expert index (``argmax``
+    takes the first maximum); their weights are the chosen scores
+    normalised to sum 1. Returns (ids (T, k) int32, weights (T, k) f32).
+    """
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    sel = s + bias.astype(jnp.float32)
+    ids = []
+    for _ in range(k):
+        i = jnp.argmax(sel, axis=-1)
+        ids.append(i)
+        sel = jnp.where(
+            jnp.arange(sel.shape[-1]) == i[:, None], -jnp.inf, sel
+        )
+    ids = jnp.stack(ids, axis=-1).astype(jnp.int32)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    return ids, w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def moe_apply_dropless(
+    params: dict,
+    x: Array,
+    ctx: AnalogCtx,
+    cfg: ModelConfig,
+    valid: Optional[Array] = None,
+) -> tuple[Array, Array]:
+    """x: (B, S, M) -> (y, stats): the held experts' part of the layer.
+
+    The router (digital, f32 at ``Precision.HIGHEST``) scores all
+    ``cfg.n_experts``; every held expert's programmed bank runs over every
+    row; a row's output is the sum, over held experts in id order, of its
+    routing weight for the expert (0 where not chosen) times the expert's
+    output, in f32. What the experts held elsewhere would add is left out.
+    ``stats`` is the int32 vector of :data:`STAT_NAMES`; ``valid`` (B, S)
+    marks the real rows it counts as tokens (all rows when None). The
+    outputs do not depend on it.
+    """
+    b, s, m = x.shape
+    t = b * s
+    held = held_experts(cfg)
+    xt = x.reshape(t, m)
+    real = jnp.ones((t,), bool) if valid is None else valid.reshape(t)
+    with jax.named_scope("router"):
+        logits = jnp.einsum(
+            "tm,me->te", xt.astype(jnp.float32), params["router"]["w"],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        ids, wts = route_sigmoid_bias(logits, params["expert_bias"], cfg.top_k)
+    with jax.named_scope("dispatch"):
+        hit = ids[:, :, None] == jnp.asarray(held, jnp.int32)  # (T, k, H)
+        gate = jnp.sum(jnp.where(hit, wts[..., None], 0.0), axis=1)  # (T, H)
+        per_expert = jnp.sum(  # (H,) real rows routed to each
+            hit & real[:, None, None], axis=(0, 1), dtype=jnp.int32
+        )
+        xe = jnp.broadcast_to(xt, (len(held), 1, t, m))
+    with jax.named_scope("experts"):
+        ye = _expert_ffn(params, xe, ctx, x.dtype)[:, 0]  # (H, T, M)
+    with jax.named_scope("combine"):
+        y = gate[:, :1] * ye[0].astype(jnp.float32)
+        for j in range(1, len(held)):
+            y = y + gate[:, j : j + 1] * ye[j].astype(jnp.float32)
+    stats = jnp.stack([
+        jnp.sum(per_expert), jnp.int32(len(held) * t), jnp.max(per_expert),
+        jnp.sum(real, dtype=jnp.int32),
+    ]).astype(jnp.int32)
+    return y.astype(x.dtype).reshape(b, s, m), stats
 
 
 def aux_load_balance_loss(logits: Array, dispatch: Array) -> Array:

@@ -36,9 +36,10 @@ Lifecycle of a request (see ``serving/scheduler.py`` for admission):
      reset (``models.lm.reset_cache_slot``), and immediately re-admittable.
 
 Because slots are independent (no cross-batch coupling outside MoE
-capacity routing), a request's generation is bit-identical to serving it
-alone on a fresh engine -- continuous batching is semantically inert; it
-only changes *when* work happens, never *what* is computed. Tests pin this.
+capacity routing; dropless MoE keeps rows apart), a request's generation
+is bit-identical to serving it alone on a fresh engine -- continuous
+batching is semantically inert; it only changes *when* work happens,
+never *what* is computed. Tests pin this.
 
 A serving run is an :class:`EngineRun`: the per-run state (queue, caches,
 slots, counters, drift bookkeeping) plus the stepping surface
@@ -77,12 +78,15 @@ from repro.core.analog import AnalogConfig
 from repro.core.engine import CiMProgram, DriftSchedule
 from repro.kernels import decode_fused
 from repro.models import attention as attn_lib
+from repro.models import moe as moe_lib
 from repro.models.common import ModelConfig
 from repro.models.lm import (
+    MOE_KINDS,
     append_cache_page,
     block_period,
     free_cache_slot_paged,
     init_lm_cache,
+    layer_kinds,
     lm_forward,
     reset_cache_slot,
     unstack_cache,
@@ -105,6 +109,12 @@ _LEGACY_CONFIG_KEYS = frozenset(
     {"n_slots", "s_max", "paged", "page_size", "n_pages",
      "prefill_buckets", "prefill_batch"}
 )
+
+
+def _count_moe(stats: np.ndarray) -> None:
+    """Record a step's dropless MoE counts (none for other models)."""
+    for name, v in zip(moe_lib.STAT_NAMES, stats):
+        obs.count(name, int(v))
 
 
 def _kv_cache_bytes(cache) -> int:
@@ -278,10 +288,15 @@ class ServingEngine:
     budget, so a lone long prompt never pays for dummy rows), and each
     bucket has exactly one ``(rows, bucket)`` shape -- the engine
     compiles at most one prefill trace per bucket. ``prefill_batch`` is
-    forced to 1 when
-    the analog config draws per-request noise (per-rid rng keys) or the
-    period contains MoE blocks (capacity routing couples batch rows);
-    both keep paged serving bit-identical to the rectangular engine.
+    forced to 1 when the analog config draws per-request noise (per-rid
+    rng keys) or a layer routes with capacity-bounded MoE dispatch
+    (capacity couples batch rows); both keep paged serving bit-identical
+    to the rectangular engine. Dropless MoE rows are independent, so it
+    batches.
+
+    A dropless MoE model's layers count their routing (``moe.STAT_NAMES``):
+    the counts come back with each prefill's and decode step's tokens, in
+    the same transfer, and are recorded with ``obs.count`` per call.
     """
 
     def __init__(
@@ -376,11 +391,16 @@ class ServingEngine:
                 raise ValueError(
                     f"prefill buckets must be >= 1: {self.prefill_buckets}"
                 )
-            # per-request rng keys and MoE capacity routing both couple a
-            # prefill batch's rows to its composition; solo prefill keeps
-            # paged serving bit-identical to the rectangular engine
+            # a row's noise key is its batch's first rid, and capacity-
+            # bounded MoE dispatch fills expert buffers across the batch:
+            # both make a row depend on the batch it lands in, so those
+            # configurations prefill alone (bit-identical to the
+            # rectangular engine); dropless MoE computes each row alone
             prefill_batch = config.prefill_batch
-            if analog_cfg.needs_rng or "moe" in block_period(model_cfg):
+            capacity_moe = model_cfg.moe_dispatch != "dropless" and bool(
+                MOE_KINDS & set(layer_kinds(model_cfg))
+            )
+            if analog_cfg.needs_rng or capacity_moe:
                 prefill_batch = 1
             self.prefill_batch = int(prefill_batch)
             # constant prefill TOKEN budget: ``prefill_batch`` rows at the
@@ -428,23 +448,26 @@ class ServingEngine:
 
         cfg, acfg, s_full = self.cfg, self.acfg, self.s_max
 
+        # every step returns (tokens, logits, cache), the tokens followed by
+        # the dropless MoE counts: the one array the host reads back
         def prefill(params, batch, rng):
             cache = init_lm_cache(cfg, 1, s_full, cfg.dtype)
-            logits, cache = lm_forward(
+            logits, cache, stats = lm_forward(
                 params, batch, acfg, cfg, cache=cache, last_token_only=True,
-                rng=rng if acfg.needs_rng else None,
+                rng=rng if acfg.needs_rng else None, moe_stats=True,
             )
             last = logits[:, -1]
             tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            return tok, last, unstack_cache(cache)
+            return jnp.concatenate([tok, stats]), last, unstack_cache(cache)
 
         def decode(params, tok, cache, rng):
-            logits, cache = lm_forward(
+            logits, cache, stats = lm_forward(
                 params, {"tokens": tok}, acfg, cfg, cache=cache,
-                rng=rng if acfg.needs_rng else None,
+                rng=rng if acfg.needs_rng else None, moe_stats=True,
             )
             last = logits[:, -1]
-            return jnp.argmax(last, axis=-1).astype(jnp.int32), last, cache
+            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([nxt, stats]), last, cache
 
         self._prefill = jax.jit(prefill)
         self._decode = jax.jit(decode, donate_argnums=(2,))
@@ -487,14 +510,16 @@ class ServingEngine:
                 # position (padding makes row ends differ).
                 pb, sb = toks.shape
                 cache = init_lm_cache(cfg, pb, sb, cfg.dtype)
-                logits, cache = lm_forward(
+                logits, cache, stats = lm_forward(
                     params, {"tokens": toks}, acfg, cfg, cache=cache,
                     last_token_only=True, last_index=last_idx,
-                    rng=rng if acfg.needs_rng else None,
+                    rng=rng if acfg.needs_rng else None, moe_stats=True,
                 )
                 last = logits[:, -1]
                 tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                return tok, last, unstack_cache(cache)
+                return (
+                    jnp.concatenate([tok, stats]), last, unstack_cache(cache)
+                )
 
             self._prefill_bucket = jax.jit(prefill_bucket)
             self._write_slot_paged = jax.jit(
@@ -732,9 +757,12 @@ class EngineRun:
             self.reserved = 0
             # KV tokens the paged kernel reads per slot and compute block
             # (None: the decode step attends over the gathered view)
-            leaf = jax.tree.leaves(
-                self.cache, is_leaf=lambda x: isinstance(x, attn_lib.PagedKVCache)
-            )[0]
+            leaf = next(
+                x for x in jax.tree.leaves(
+                    self.cache,
+                    is_leaf=lambda x: isinstance(x, attn_lib.PagedKVCache),
+                ) if isinstance(x, attn_lib.PagedKVCache)
+            )
             self.kv_block = (
                 attn_lib.kernel_block_pages(
                     engine.page_size, engine.pages_per_slot
@@ -938,7 +966,9 @@ class EngineRun:
                 self._count_decision(logits0, r_logits, 0)
             with obs.span("engine.first_token", self.now_fn):
                 # repro-lint: disable=RL004 -- one sync per ADMISSION (not per decode tick): the first token must reach the host record
-                first = int(tok0[0])
+                host = np.asarray(tok0)
+            _count_moe(host[1:])
+            first = int(host[0])
             self.slots[slot] = _Slot(
                 req, [first], self.steps, self.now_fn() - self.t_start,
                 logits=self._logits_rows(logits0, 0),
@@ -976,8 +1006,10 @@ class EngineRun:
                 toks[j, : req.prompt.size] = req.prompt
                 lens[j] = req.prompt.size
             for j in range(len(chunk), pb):
-                toks[j] = toks[0]  # dummy rows repeat row 0
-                lens[j] = lens[0]
+                # dummy rows repeat row 0's tokens and hold no request:
+                # length 0 (last index -1), so they count no real token
+                toks[j] = toks[0]
+                lens[j] = 0
             eng._prefill_shapes.add((pb, sb))
             with obs.span("engine.prefill", self.now_fn, a=sb, b=pb):
                 obs.count("engine.prefill_tokens_real",
@@ -991,6 +1023,7 @@ class EngineRun:
                         eng.rng, 1_000_000 + chunk[0].rid
                     ),
                 )
+            host = None
             for j, req in enumerate(chunk):
                 slot = free.pop(0)
                 n_prompt = int(req.prompt.size)
@@ -1014,9 +1047,12 @@ class EngineRun:
                         self.ref_cache, r_pcache, jnp.int32(slot)
                     )
                     self._count_decision(logitsv[j : j + 1], r_logits, 0)
-                with obs.span("engine.first_token", self.now_fn):
-                    # repro-lint: disable=RL004 -- one sync per ADMISSION (bucketed prefill), amortized over the request's whole decode
-                    first = int(tokv[j])
+                if host is None:
+                    with obs.span("engine.first_token", self.now_fn):
+                        # repro-lint: disable=RL004 -- one sync per prefill call (every row's first token and the MoE counts), amortized over the requests' whole decode
+                        host = np.asarray(tokv)
+                    _count_moe(host[pb:])
+                first = int(host[j])
                 self.slots[slot] = _Slot(
                     req, [first], self.steps,
                     self.now_fn() - self.t_start,
@@ -1050,7 +1086,11 @@ class EngineRun:
             with obs.span("engine.decode_sync", now_fn):
                 if eng._ref:
                     a_np, e_np = np.asarray(a_v), np.asarray(e_v)
-                nxt_np = np.asarray(nxt)
+                host = np.asarray(nxt)
+            nxt_np = host[: eng.n_slots]
+            _count_moe(host[eng.n_slots:])
+            if nxt.shape[0] > eng.n_slots:
+                nxt = nxt[: eng.n_slots]
             with obs.span("engine.tokens", now_fn):
                 self.steps += 1
                 active = [i for i, s in enumerate(self.slots) if s is not None]

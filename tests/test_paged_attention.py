@@ -102,3 +102,27 @@ def test_the_cpu_lowers_the_view_path():
     assert "tpu_custom_call" not in fn.lower(q, cache).as_text()
     np.testing.assert_array_equal(np.asarray(fn(q, cache), np.float32),
                                   _view(q, cache))
+
+
+def test_padded_pool_of_64_wide_gqa_heads():
+    """LFM2's heads: 64 wide, 4 query heads a KV head. A pool made for a
+    TPU has its head axis padded to the kernel's 128 lanes
+    (``pool_head_dim``), elsewhere not: the
+    kernel on the padded pool matches the view of the unpadded one, and the
+    view path of the padded pool is bitwise the unpadded one's."""
+    assert attn_lib.pool_head_dim(64, "tpu") == 128
+    assert attn_lib.pool_head_dim(128, "tpu") == 128
+    assert attn_lib.pool_head_dim(64, "cpu") == 64
+    base = _cache(17)
+    small = base._replace(k=base.k[..., :64], v=base.v[..., :64])
+    padded = small._replace(k=attn_lib.pad_head(small.k, 128),
+                            v=attn_lib.pad_head(small.v, 128))
+    q = jax.random.normal(jax.random.PRNGKey(1), (B, 1, 4 * N_KV, 64))
+    q = q.astype(jnp.bfloat16)
+    want = _view(q, small)
+    got = _kernel(q, padded)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got[:2], want[:2], rtol=0, atol=2**-6)
+    assert not np.any(got[2])
+    view = jax.jit(attn_lib._view_path)
+    np.testing.assert_array_equal(np.asarray(view(q, padded), np.float32), want)

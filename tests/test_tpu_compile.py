@@ -144,3 +144,61 @@ def test_paged_decode_attention_is_the_kernel_for_v5e(one_chip):
     pool = "bf16[{}]".format(",".join(map(str, cache.k.shape)))
     copies = [ln for ln in text.splitlines() if f"= {pool}" in ln and " copy(" in ln]
     assert not copies, copies
+
+
+def test_lfm2_cell_decode_and_4096_prefill_compile_for_v5e(one_chip):
+    """The engine's programs at the lfm2moe-b8-conv cell's shapes (LFM2's
+    first six layers at published widths, 8 of 32 experts held, a 16384-row
+    head; 64 slots of 4096 tokens in 16-token pages, one conv state per
+    slot and conv layer): the decode step, with the paged-attention kernel
+    over GQA 32/8 and the MoE counts in its token vector, and the prefill
+    at the 4096 bucket both compile for a described v5e, and neither needs
+    more device memory than the chip has left beside the programmed chip
+    and the cache."""
+    import json
+    import pathlib
+
+    from benchmarks.chip.drivers import lfm2moe_serve
+    from repro.serving import ServingConfig, ServingEngine
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    c = json.loads((root / "benchmarks/chip/configs/lfm2moe-b8.json").read_text())
+    mc = lfm2moe_serve.model_config(c)
+    acfg = AnalogConfig().infer(b_adc=8)
+    def programmed(k):
+        prog = engine.compile_program(lm.lm_init(k, mc), acfg, k)
+        return prog.params, prog.state
+
+    params, state = jax.eval_shape(programmed, jax.random.PRNGKey(0))
+    import dataclasses
+
+    pcfg = dataclasses.replace(acfg, mode=engine.PCM_PROGRAMMED)
+    n_slots, s_max = c["n_slots"], c["s_max"]
+    eng = ServingEngine(mc, pcfg, params, ServingConfig(
+        n_slots=n_slots, s_max=s_max, paged=True, page_size=c["page_size"],
+        ref_check=False))
+    # pools made for a TPU: the head axis is padded to the kernel's lanes
+    cache = jax.eval_shape(lambda: lm.init_lm_cache(
+        mc, n_slots, s_max, mc.dtype, stacked=False, paged=True,
+        page_size=c["page_size"], platform="tpu"))
+    assert cache[0] == [] and cache[1][2].k.shape[-1] == 128
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t
+    )
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    decode = eng._decode.lower(
+        place(params), i32(n_slots, 1), place(cache), key).compile()
+    assert "tpu_custom_call" in decode.as_text()
+    out = decode.out_info[0]
+    assert out.shape == (n_slots + 4,)  # the tokens, then the MoE counts
+    prefill = eng._prefill_bucket.lower(
+        place(params), i32(1, s_max), i32(1), key).compile()
+    # held on the chip: the effective weights, the PCM state they were
+    # programmed from (kept for ageing) and the paged cache; 16e9 leaves
+    # room below the v5e's 16.9e9 for the allocator and the embedding copy
+    resident = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves((params, state, cache)))
+    for prog in (decode, prefill):
+        temp = prog.memory_analysis().temp_size_in_bytes
+        assert resident + temp < 16e9, (resident, temp)
